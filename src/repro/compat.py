@@ -1,82 +1,29 @@
-"""jax version-compatibility shims (single source; tests import it too).
+"""The one place this repo touches ``jax.experimental`` (repro-lint RL005).
 
-The repo targets the current jax API surface — ``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)`` — but must also run
-on older installs where ``shard_map`` still lives in ``jax.experimental``
-(flag named ``check_rep``) and ``Mesh`` has no axis types. Every module
-that builds a mesh or wraps a function in shard_map goes through these
-two helpers instead of touching ``jax.*`` directly, so the version split
-lives in exactly one place.
-
-``compiled_cost_analysis`` papers over the other drift point: older jax
-returns ``Compiled.cost_analysis()`` as a one-element list, newer jax as
-the dict itself.
+Pallas still lives under ``jax.experimental``; re-exporting it here keeps
+that import surface at one call site, so when it graduates (or the TPU
+submodule moves again) only this file changes. ``make_mesh`` and
+``shard_map`` pin the two defaults every mesh user in the repo relies on:
+Auto mesh axes and unchecked replication.
 """
 
 from __future__ import annotations
 
 import jax
-
-try:  # newer jax: explicit mesh axis types
-    from jax.sharding import AxisType  # noqa: F401
-    _HAS_AXIS_TYPE = True
-except ImportError:
-    AxisType = None
-    _HAS_AXIS_TYPE = False
+from jax.experimental import pallas  # noqa: F401
+from jax.experimental.pallas import tpu as pallas_tpu  # noqa: F401
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes, axis_types=None):
-    """``jax.make_mesh`` with Auto axis types where the API has them.
-
-    ``axis_types`` may be ``None`` (= all Auto) or a tuple matching
-    ``axes``; on jax versions without ``AxisType`` it is ignored (those
-    versions have no manual/auto distinction to declare).
-    """
-    if _HAS_AXIS_TYPE:
-        if axis_types is None:
-            axis_types = (AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=axis_types)
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types unless ``axis_types`` says
+    otherwise (a tuple matching ``axes``)."""
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=axis_types)
 
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-        """Current-API ``jax.shard_map`` (vma checking flag passthrough)."""
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-        """Legacy ``jax.experimental.shard_map`` (flag named check_rep)."""
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-
-
-if hasattr(jax.lax, "axis_size"):
-    axis_size = jax.lax.axis_size
-else:
-    def axis_size(axis_name):
-        """Mapped-axis size inside shard_map on jax without lax.axis_size."""
-        return jax.lax.psum(1, axis_name)
-
-
-# Pallas still lives under jax.experimental; re-exporting it here keeps
-# the experimental import surface at one call site (repro-lint RL005), so
-# when it graduates (or the tpu submodule moves again) only compat.py
-# changes. ``pallas_tpu`` is None on builds without the TPU backend
-# extension; kernels guard on it before using TPU-only primitives.
-from jax.experimental import pallas  # noqa: E402,F401
-
-try:
-    from jax.experimental.pallas import tpu as pallas_tpu  # noqa: E402
-except ImportError:
-    pallas_tpu = None
-
-
-def compiled_cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a dict on every jax version."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
-    return ca
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
+    """``jax.shard_map`` with replication checking off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

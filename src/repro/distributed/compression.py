@@ -19,8 +19,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 
 @dataclasses.dataclass
 class CompressionState:
@@ -40,7 +38,7 @@ def compressed_psum(g: jax.Array, axis_name: str,
     exact in the quantized domain, so compression error comes only from the
     local quantization step (which error feedback absorbs).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     g32 = g.astype(jnp.float32)
     if state is not None:
         g32 = g32 + state.residual

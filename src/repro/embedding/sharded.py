@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
+from repro.compat import shard_map
 
 
 def local_shard_lookup(local_table: jax.Array, indices: jax.Array,
@@ -102,7 +102,7 @@ def sharded_embedding_bag_2d(table: jax.Array, indices: jax.Array,
     """
     rows_per_shard = table.shape[0]
     idx_full = jax.lax.all_gather(indices, data_axis, axis=0, tiled=True)
-    sid = (jax.lax.axis_index(model_axis) * axis_size(data_axis)
+    sid = (jax.lax.axis_index(model_axis) * jax.lax.axis_size(data_axis)
            + jax.lax.axis_index(data_axis))
     if rank_of is not None:
         # phase 1: logical id -> stored rank through the sharded hash table

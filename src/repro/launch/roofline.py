@@ -76,8 +76,6 @@ def analyze(compiled, n_chips: int, model_flops: float | None = None,
             hw: dict = V5E) -> Roofline:
     """Derive the three roofline terms from a compiled executable."""
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):    # older jax returned [dict]
-        ca = ca[0]
     stats = hlo_stats(compiled.as_text(), n_chips)
     flops = float(stats["flops"])
     bytes_acc = float(stats["bytes"])

@@ -6,10 +6,11 @@ stream, wait in the ``RequestQueue``, are coalesced by the ``DynamicBatcher``
 lane per NAND access policy, each lane ``--channels`` concurrent SLS
 servers — so the identical stream is replayed against RecSSD / RM-SSD /
 RecFlash and per-request p50/p95/p99 latency and throughput come out per
-policy (DESIGN.md §3). In parallel, the TPU half scores the RecFlash lane's
+policy (DESIGN.md §3). Then the device half scores the RecFlash lane's
 batches through the jitted DLRM forward (tables stored frequency-remapped,
 logical ids translated via the rank_of hash table), padded to a single
-compiled shape.
+shape that is compiled before the timed loop. Tables that do not fit the
+device are an error, not a skipped half; ``--skip-compute`` opts out.
 
     PYTHONPATH=src python -m repro.launch.serve --requests 200 --batch 64
 """
@@ -17,6 +18,8 @@ compiled shape.
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import functools
 import time
 
 import jax
@@ -26,6 +29,7 @@ import numpy as np
 import repro.models.dlrm as dlrm
 from repro.embedding.layout import RemapSpec, remap_table
 from repro.flashsim.timeline import SERVING_POLICIES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import (BatcherConfig, Deployment, DeploymentConfig,
                            arch_model_config)
 
@@ -33,39 +37,101 @@ from repro.serving import (BatcherConfig, Deployment, DeploymentConfig,
 POLICY_NAMES = SERVING_POLICIES
 
 
-def score_batches(batches, params, cfg, rank_ofs, dense_all, max_batch: int):
-    """TPU half: jitted forward over the lane's batches, one compiled shape.
+@dataclasses.dataclass
+class Scored:
+    """What the device half scored, one row per request in dispatch order."""
+
+    cfg: dlrm.DLRMConfig
+    rids: np.ndarray        # (n,) request ids
+    indices: np.ndarray     # (n, n_tables, lookups) logical row ids
+    dense: np.ndarray       # (n, n_dense) dense features
+    logits: np.ndarray      # (n,) device logits
+    compile_s: float        # lower + compile of the one padded shape
+    steady_s: float         # summed step time after compilation
+    n_batches: int
+
+
+def check_fits(need_bytes: int, device) -> None:
+    """Raise unless ``device`` has ``need_bytes`` free. A backend that
+    reports no memory statistics (the CPU) is not checked."""
+    stats = device.memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return
+    free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+    if need_bytes > free:
+        raise RuntimeError(
+            f"the model's tables and rank_of arrays need {need_bytes} B on "
+            f"{device.device_kind} but {free} B of {stats['bytes_limit']} B "
+            f"are free; cut the tables with --rows or pass --skip-compute")
+
+
+def place_tables(cfg: dlrm.DLRMConfig, specs: list[RemapSpec], seed: int):
+    """Initialise the model on the device and store every table
+    frequency-remapped. Tables are remapped one at a time and each original
+    is released before the next, so peak memory stays near one copy."""
+    params = dlrm.init(jax.random.PRNGKey(seed), cfg)
+    tables = params["tables"]
+    for t, spec in enumerate(specs):
+        tables[t] = remap_table(tables[t], spec).block_until_ready()
+    rank_ofs = [jnp.asarray(s.rank_of, jnp.int32) for s in specs]
+    return params, rank_ofs
+
+
+@functools.partial(jax.jit, static_argnames="cfg")
+def serve_step(params, rank_ofs, batch, cfg: dlrm.DLRMConfig):
+    """The served step: DLRM forward over frequency-remapped tables. The
+    ``rank_ofs`` hash tables are arguments, so no table-sized constant is
+    baked into the program."""
+    return dlrm.forward(dlrm.add_remap(params, rank_ofs), batch, cfg)
+
+
+def _pad(x: np.ndarray, rows: int) -> np.ndarray:
+    """Pad to ``rows`` by replicating row 0."""
+    pad = rows - x.shape[0]
+    return np.concatenate([x, np.repeat(x[:1], pad, axis=0)]) if pad else x
+
+
+def score_batches(batches, params, cfg, rank_ofs, dense_all,
+                  max_batch: int) -> Scored:
+    """Device half: jitted forward over the lane's batches, one compiled shape.
 
     Batches are padded to ``max_batch`` rows (row 0 replicated) so every
-    dispatch hits the same jit cache entry; only real rows are counted.
+    dispatch runs the one program compiled before the timed loop; only real
+    rows are scored.
     """
+    shape = {"dense": jax.ShapeDtypeStruct((max_batch, cfg.n_dense),
+                                           jnp.float32),
+             "indices": jax.ShapeDtypeStruct(
+                 (max_batch, cfg.n_tables, cfg.lookups), jnp.int32)}
+    t0 = time.perf_counter()
+    step = serve_step.lower(params, rank_ofs, shape, cfg=cfg).compile()
+    compile_s = time.perf_counter() - t0
 
-    @jax.jit
-    def serve_step(p, batch):
-        return dlrm.forward(dlrm.add_remap(p, rank_ofs), batch, cfg)
-
-    t_compute = 0.0
-    n_scored = 0
+    rids, idxs, denses, logits = [], [], [], []
+    steady_s = 0.0
     for b in batches:
-        rids = np.array([r.rid for r in b.requests])
+        rid = np.array([r.rid for r in b.requests])
         idx = np.stack([r.rows.reshape(cfg.n_tables, cfg.lookups)
                         for r in b.requests])
-        pad = max_batch - idx.shape[0]
-        if pad:
-            idx = np.concatenate([idx, np.repeat(idx[:1], pad, axis=0)])
-        dense = dense_all[rids]
-        if pad:
-            dense = np.concatenate([dense, np.repeat(dense[:1], pad, axis=0)])
-        batch = {"dense": jnp.asarray(dense, jnp.float32),
-                 "indices": jnp.asarray(idx, jnp.int32)}
-        t0 = time.time()
-        jax.block_until_ready(serve_step(params, batch))
-        t_compute += time.time() - t0
-        n_scored += len(b.requests)
-    return t_compute, n_scored
+        dense = dense_all[rid]
+        batch = {"dense": jnp.asarray(_pad(dense, max_batch), jnp.float32),
+                 "indices": jnp.asarray(_pad(idx, max_batch), jnp.int32)}
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(step(params, rank_ofs, batch))
+        steady_s += time.perf_counter() - t0
+        rids.append(rid)
+        idxs.append(idx)
+        denses.append(dense)
+        logits.append(np.asarray(out[:len(rid)]))
+    return Scored(cfg=cfg, rids=np.concatenate(rids),
+                  indices=np.concatenate(idxs), dense=np.concatenate(denses),
+                  logits=np.concatenate(logits), compile_s=compile_s,
+                  steady_s=steady_s, n_batches=len(batches))
 
 
-def main() -> int:
+def main(argv: list[str] | None = None) -> Scored | None:
+    """Run the driver; return what the device half scored (None with
+    ``--skip-compute``)."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--requests", type=int, default=50,
                     help="number of inference requests in the stream")
@@ -73,8 +139,8 @@ def main() -> int:
                     help="registry arch for shapes (dlrm_small, dlrm_rm2, "
                          "dlrm_mlperf, rmc1/2/3)")
     ap.add_argument("--rows", type=int, default=None,
-                    help="override rows per table (scales full-size archs "
-                         "down so the jit compute half stays feasible)")
+                    help="override rows per table (cuts a full-size arch "
+                         "down, e.g. for a CPU run)")
     ap.add_argument("--batch", type=int, default=64,
                     help="dynamic batcher max batch size (requests)")
     ap.add_argument("--max-wait-us", type=float, default=1000.0,
@@ -91,7 +157,8 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--skip-compute", action="store_true",
                     help="storage-side simulation only (no jit forward)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # --- the deployment: one declarative config, one facade ---------------
     dep_cfg = DeploymentConfig.from_arch(
@@ -109,30 +176,21 @@ def main() -> int:
     traces = dep.run_stream(requests)
     t_sim = time.time() - t0
 
-    # --- compute half: score the RecFlash lane's batches on the TPU -------
-    # full-scale registry archs (e.g. dlrm_rm2: 26 x 1M x 64 fp32 tables)
-    # would materialise many GB twice (init + remapped copy); the storage
-    # simulation above never builds them, so auto-skip the jit forward
-    # rather than OOM. Scale down with --rows to keep the compute half.
-    table_gb = sum(t.n_rows * t.vec_bytes for t in dep_cfg.tables) / 2**30
-    if not args.skip_compute and table_gb > 2.0:
-        print(f"[serve] compute half skipped: {args.arch} model tables are "
-              f"~{table_gb:.1f} GiB (x2 with the remapped copy); pass "
-              f"--rows to scale tables down or --skip-compute to silence")
-        args.skip_compute = True
+    # --- compute half: score the RecFlash lane's batches on the device ----
+    scored = None
     if not args.skip_compute:
-        params = dlrm.init(jax.random.PRNGKey(args.seed), cfg)
-        params["tables"] = [remap_table(tbl, s)
-                            for tbl, s in zip(params["tables"], specs, strict=True)]
-        rank_ofs = [jnp.asarray(s.rank_of) for s in specs]
+        device = jax.devices()[0]
+        check_fits(sum(n * (cfg.embed_dim + 1) * 4 for n in cfg.n_rows),
+                   device)
+        params, rank_ofs = place_tables(cfg, specs, args.seed)
         dense_all = np.random.default_rng(args.seed * 7919).normal(
             size=(args.requests, cfg.n_dense)).astype(np.float32)
-        t_compute, n_scored = score_batches(
-            traces["recflash"].batches, params, cfg, rank_ofs, dense_all,
-            args.batch)
-        n_b = max(1, len(traces["recflash"].batches))
-        print(f"scored {n_scored} requests in {t_compute:.2f}s compute "
-              f"({1e3 * t_compute / n_b:.2f} ms/batch jit forward)")
+        scored = score_batches(traces["recflash"].batches, params, cfg,
+                               rank_ofs, dense_all, args.batch)
+        print(f"scored {len(scored.rids)} requests on {device.platform} "
+              f"({device.device_kind}): compile {scored.compile_s:.2f}s, "
+              f"steady {1e3 * scored.steady_s / max(1, scored.n_batches):.2f}"
+              f" ms/batch over {scored.n_batches} batches")
 
     # --- report -----------------------------------------------------------
     print(f"\n{args.arrival} arrivals @ {args.rate:.0f} req/s, "
@@ -148,8 +206,8 @@ def main() -> int:
               f"{1 - r_flash.p99_us / r_rmssd.p99_us:.1%} lower p99, "
               f"{r_flash.throughput_rps / max(r_rmssd.throughput_rps, 1e-9):.2f}x "
               f"throughput")
-    return 0
+    return scored
 
 
 if __name__ == "__main__":
-    raise SystemExit(main())
+    main()

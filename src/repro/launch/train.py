@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import optim
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import LoopConfig, TrainLoop
 
 
@@ -125,6 +126,7 @@ def main() -> int:
                     help="disable the RecFlash AF table remap (baseline)")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.model == "dlrm":
         params, opt, loss_fn, batch_fn = _dlrm_pipeline(

@@ -27,7 +27,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
 from repro.models.common import dense_init, normal_init
 
 
@@ -255,7 +254,7 @@ def _moe_2d_block(params, x2d, cfg: MoEConfig, model_axis, data_axis,
     # slice this shard's batch rows back out (batch-major gather order)
     idx = 0
     for ax in batch_axes:
-        idx = idx * axis_size(ax) + jax.lax.axis_index(ax)
+        idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return jax.lax.dynamic_slice_in_dim(y, idx * rows, rows, axis=0)
 
 

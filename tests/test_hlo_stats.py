@@ -10,7 +10,6 @@ import sys
 import jax
 import jax.numpy as jnp
 
-from repro.compat import compiled_cost_analysis
 from repro.launch.hlo_stats import hlo_stats
 
 ENV = {**os.environ,
@@ -47,7 +46,7 @@ class TestFlopsCounting:
         assert s["flops"] == 12 * 2 * 32 * 64 * 64
         # XLA cost_analysis undercounts (body visited once) — our reason
         # for existing:
-        assert compiled_cost_analysis(c)["flops"] < s["flops"]
+        assert c.cost_analysis()["flops"] < s["flops"]
 
     def test_nested_scans_multiply(self):
         x = jax.ShapeDtypeStruct((16, 16), jnp.float32)

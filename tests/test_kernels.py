@@ -67,6 +67,37 @@ class TestRecFlashSLS:
         np.testing.assert_allclose(out, ops.sls_ref(hot, cold, idx),
                                    rtol=1e-5, atol=1e-6)
 
+    def test_hot_tier_over_vmem_budget_raises(self):
+        """The pinned hot tier is checked against the VMEM budget before
+        anything is built (shapes only, nothing allocated)."""
+        from repro.kernels.recflash_sls import HOT_VMEM_BUDGET
+        d = 128
+        h = HOT_VMEM_BUDGET // (d * 4) + 8
+        args = (jax.ShapeDtypeStruct((h, d), jnp.bfloat16),
+                jax.ShapeDtypeStruct((4 * h, d), jnp.bfloat16),
+                jax.ShapeDtypeStruct((8, 4), jnp.int32))
+        with pytest.raises(ValueError, match=str(HOT_VMEM_BUDGET)):
+            jax.eval_shape(
+                lambda a, b, c: sls_raw(a, b, c, interpret=True), *args)
+
+    @pytest.mark.parametrize("d", [48, 192])
+    def test_dim_must_tile_the_lanes(self, d):
+        hot, cold, idx = _inputs(8, 32, d, 8, 2, jnp.float32)
+        with pytest.raises(ValueError, match="divide 128"):
+            sls_raw(hot, cold, idx, block_b=8, interpret=True)
+
+    def test_uneven_tiers_are_padded_to_whole_lines(self):
+        """H and V-H that are not multiples of the rows per lane line."""
+        hot, cold, idx = _inputs(7, 70, 32, 8, 9, jnp.float32)
+        out = sls_raw(hot, cold, idx, block_b=8, interpret=True)
+        np.testing.assert_allclose(out, ops.sls_ref(hot, cold, idx),
+                                   rtol=1e-5, atol=1e-6)
+
+    def test_interpret_only_on_cpu(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops._interpret()
+
 
 class TestDotInteraction:
     @pytest.mark.parametrize("b,t,d", [(64, 9, 16), (128, 27, 64),

@@ -6,8 +6,12 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 ROOT = os.path.join(os.path.dirname(__file__), "..")
-ENV = {**os.environ, "PYTHONPATH": os.path.join(ROOT, "src")}
+# the entry points turn on the persistent compilation cache; tests keep it off
+ENV = {**os.environ, "PYTHONPATH": os.path.join(ROOT, "src"),
+       "JAX_ENABLE_COMPILATION_CACHE": "false"}
 
 
 def run(args, timeout=420, env=ENV):
@@ -41,6 +45,42 @@ class TestDrivers:
         # the RecFlash policy must win on the simulated device
         pct = float(out.split("recflash vs rmssd:")[1].split("%")[0])
         assert pct > 0
+
+    def test_serve_main_scores_every_request_on_the_device(self,
+                                                            monkeypatch):
+        """In-process: main takes argv and returns what it scored; every
+        request of the RecFlash lane is scored once, after the compile."""
+        import numpy as np
+
+        from repro.launch import serve
+        monkeypatch.setattr(serve, "enable_compile_cache", lambda: "")
+        scored = serve.main(["--requests", "12", "--batch", "4",
+                             "--rows", "2000"])
+        assert sorted(scored.rids.tolist()) == list(range(12))
+        assert scored.logits.shape == (12,)
+        assert np.isfinite(scored.logits).all()
+        assert scored.indices.shape == (12, scored.cfg.n_tables,
+                                        scored.cfg.lookups)
+        assert scored.compile_s > 0 and scored.n_batches >= 3
+        monkeypatch.setattr(serve, "enable_compile_cache", lambda: "")
+        assert serve.main(["--requests", "4", "--skip-compute"]) is None
+
+    def test_tables_that_do_not_fit_the_device_raise(self):
+        from repro.launch.serve import check_fits
+
+        class Chip:
+            device_kind = "TPU test"
+
+            def __init__(self, stats):
+                self.stats = stats
+
+            def memory_stats(self):
+                return self.stats
+
+        with pytest.raises(RuntimeError, match="2000 B .* 800 B of 1000 B"):
+            check_fits(2000, Chip({"bytes_limit": 1000, "bytes_in_use": 200}))
+        check_fits(800, Chip({"bytes_limit": 1000, "bytes_in_use": 200}))
+        check_fits(2000, Chip(None))       # no statistics: not checked
 
 
 class TestPlanConstruction:
