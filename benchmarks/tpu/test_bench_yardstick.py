@@ -1,0 +1,81 @@
+"""The benchmark's yardstick: operation and byte counts, and the peaks table."""
+
+import json
+from pathlib import Path
+
+import numpy as np
+import pytest
+from chipbench import yardstick
+
+HERE = Path(__file__).resolve().parent
+CONFIGS = sorted((HERE / "configs").glob("*.json"))
+
+
+def _cfg(path: Path) -> dict:
+    return json.loads(path.read_text())
+
+
+@pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
+def test_flops_are_the_programs_less_what_is_not_required(path):
+    from repro.serving import DeploymentConfig, arch_model_config
+
+    cfg = _cfg(path)
+    model = arch_model_config(DeploymentConfig.from_arch(cfg["arch"]))
+    d, n = cfg["embed_dim"], cfg["n_tables"] + 1
+    not_required = (2 * d * d                       # no embed_dim->embed_dim layer
+                    + 2 * n * n * d - n * (n - 1) * d   # lower triangle, diagonal
+                    + cfg["n_tables"] * cfg["lookups"] * d)  # SLS multiplies
+    assert yardstick.flops_per_sample(cfg) \
+        == model.flops_per_sample() - not_required
+
+
+@pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
+def test_mlp_weight_bytes_match_the_programs_parameters(path):
+    import jax
+
+    import repro.models.dlrm as dlrm
+    from repro.serving import DeploymentConfig, arch_model_config
+
+    cfg = _cfg(path)
+    model = arch_model_config(DeploymentConfig.from_arch(
+        cfg["arch"], n_rows=8))
+    params = jax.eval_shape(lambda: dlrm.init(jax.random.PRNGKey(0), model))
+    mlp = [leaf for key in ("bot", "top")
+           for leaf in jax.tree.leaves(params[key])]
+    assert yardstick.mlp_weight_bytes(cfg) == sum(4 * x.size for x in mlp)
+
+
+def test_step_bytes_count_distinct_rows_once_per_table():
+    cfg = {"n_tables": 2, "n_dense": 3, "embed_dim": 4, "lookups": 3,
+           "bot_mlp": [4], "top_mlp": [2], "interaction": "dot"}
+    # request 0: table 0 rows {5, 5, 7}, table 1 rows {1, 2, 3}
+    # request 1: table 0 rows {7, 8, 5}, table 1 rows {3, 3, 3}
+    indices = np.array([[[5, 5, 7], [1, 2, 3]],
+                        [[7, 8, 5], [3, 3, 3]]])
+    assert yardstick.distinct_rows(indices) == 3 + 3
+    # bottom 3->4 (12 + 4), top 5->2->1 (10 + 2, 2 + 1); top_in = 4 + 3
+    weights = 4 * (3 * 4 + 4 + 7 * 2 + 2 + 2 * 1 + 1)
+    assert yardstick.mlp_weight_bytes(cfg) == weights
+    want = (6 * 4 * 4          # distinct rows x embed_dim x 4 B
+            + weights
+            + 2 * 3 * 4        # dense features of two requests
+            + 2 * 2 * 3 * 4    # their indices
+            + 2 * 4)           # their logits
+    assert yardstick.step_bytes(cfg, indices) == want
+
+
+def test_least_time_names_the_bound():
+    peaks = {"flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
+    assert yardstick.least_time_s(50.0, 20.0, peaks) == (2.0, "bytes")
+    assert yardstick.least_time_s(500.0, 20.0, peaks) == (5.0, "flops")
+
+
+def test_unknown_device_kind_is_an_error():
+    with pytest.raises(KeyError, match="no peaks for device kind"):
+        yardstick.load_peaks(HERE / "peaks.json", "TPU v9 imaginary")
+
+
+def test_v5e_peaks_are_the_published_ones():
+    peaks = yardstick.load_peaks(HERE / "peaks.json", "TPU v5 lite")
+    assert peaks == {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9,
+                     "hbm_bytes": 16e9}
