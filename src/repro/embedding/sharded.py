@@ -14,6 +14,10 @@ plane-parallel SLS) is explicit:
 Combined with ``RemapSpec(plane_distribute=True)`` the hot rows are striped
 across shards, so the psum partial work is balanced (PD, Fig. 5c at shard
 granularity).
+
+The pooled lookup runs under the named scope ``sls`` and the two-phase
+translation under ``translate``, as on the single-device path of
+``repro.models.dlrm``, so a profile splits the two.
 """
 
 from __future__ import annotations
@@ -53,19 +57,20 @@ def sharded_embedding_bag(table: jax.Array, indices: jax.Array,
     downstream (interaction + MLPs) then runs batch-split across the model
     axis too ("hybrid sharding", §Perf H3).
     """
-    rows_per_shard = table.shape[0]
-    shard_id = jax.lax.axis_index(axis_name)
-    vecs = local_shard_lookup(table, indices, shard_id, rows_per_shard)
-    if mode == "sum":
-        pooled = vecs.sum(axis=-2)
-    elif mode == "mean":
-        pooled = vecs.sum(axis=-2) / indices.shape[-1]
-    else:
-        raise ValueError(f"unsupported distributed mode {mode!r}")
-    if scatter:
-        return jax.lax.psum_scatter(pooled, axis_name,
-                                    scatter_dimension=0, tiled=True)
-    return jax.lax.psum(pooled, axis_name)
+    with jax.named_scope("sls"):
+        rows_per_shard = table.shape[0]
+        shard_id = jax.lax.axis_index(axis_name)
+        vecs = local_shard_lookup(table, indices, shard_id, rows_per_shard)
+        if mode == "sum":
+            pooled = vecs.sum(axis=-2)
+        elif mode == "mean":
+            pooled = vecs.sum(axis=-2) / indices.shape[-1]
+        else:
+            raise ValueError(f"unsupported distributed mode {mode!r}")
+        if scatter:
+            return jax.lax.psum_scatter(pooled, axis_name,
+                                        scatter_dimension=0, tiled=True)
+        return jax.lax.psum(pooled, axis_name)
 
 
 def make_sharded_bag(mesh, table_spec: P, index_spec: P, out_spec: P,
@@ -101,25 +106,28 @@ def sharded_embedding_bag_2d(table: jax.Array, indices: jax.Array,
     hybrid-sharded layout the dense path consumes.
     """
     rows_per_shard = table.shape[0]
-    idx_full = jax.lax.all_gather(indices, data_axis, axis=0, tiled=True)
-    sid = (jax.lax.axis_index(model_axis) * jax.lax.axis_size(data_axis)
-           + jax.lax.axis_index(data_axis))
+    with jax.named_scope("sls"):
+        idx_full = jax.lax.all_gather(indices, data_axis, axis=0, tiled=True)
+        sid = (jax.lax.axis_index(model_axis) * jax.lax.axis_size(data_axis)
+               + jax.lax.axis_index(data_axis))
     if rank_of is not None:
         # phase 1: logical id -> stored rank through the sharded hash table
-        local = idx_full - sid * rows_per_shard
-        ok = (local >= 0) & (local < rows_per_shard)
-        clamped = jnp.clip(local, 0, rows_per_shard - 1)
-        ranks = jnp.where(ok, jnp.take(rank_of, clamped, axis=0), 0)
-        idx_full = jax.lax.psum(ranks, (data_axis, model_axis))
-    vecs = local_shard_lookup(table, idx_full, sid, rows_per_shard)
-    if mode == "sum":
-        pooled = vecs.sum(axis=-2)
-    elif mode == "mean":
-        pooled = vecs.sum(axis=-2) / indices.shape[-1]
-    else:
-        raise ValueError(f"unsupported distributed mode {mode!r}")
-    return jax.lax.psum_scatter(pooled, (data_axis, model_axis),
-                                scatter_dimension=0, tiled=True)
+        with jax.named_scope("translate"):
+            local = idx_full - sid * rows_per_shard
+            ok = (local >= 0) & (local < rows_per_shard)
+            clamped = jnp.clip(local, 0, rows_per_shard - 1)
+            ranks = jnp.where(ok, jnp.take(rank_of, clamped, axis=0), 0)
+            idx_full = jax.lax.psum(ranks, (data_axis, model_axis))
+    with jax.named_scope("sls"):
+        vecs = local_shard_lookup(table, idx_full, sid, rows_per_shard)
+        if mode == "sum":
+            pooled = vecs.sum(axis=-2)
+        elif mode == "mean":
+            pooled = vecs.sum(axis=-2) / indices.shape[-1]
+        else:
+            raise ValueError(f"unsupported distributed mode {mode!r}")
+        return jax.lax.psum_scatter(pooled, (data_axis, model_axis),
+                                    scatter_dimension=0, tiled=True)
 
 
 def sharded_remapped_bag(table: jax.Array, rank_of: jax.Array,
@@ -138,12 +146,13 @@ def sharded_remapped_bag(table: jax.Array, rank_of: jax.Array,
     ``table`` (rows/shard, D) is stored rank-ordered; ``rank_of``
     (rows/shard,) holds the ranks of this shard's *logical* id range.
     """
-    rows_per_shard = rank_of.shape[0]
-    shard_id = jax.lax.axis_index(axis_name)
-    local = indices - shard_id * rows_per_shard
-    ok = (local >= 0) & (local < rows_per_shard)
-    clamped = jnp.clip(local, 0, rows_per_shard - 1)
-    ranks = jnp.where(ok, jnp.take(rank_of, clamped, axis=0), 0)
-    ranks = jax.lax.psum(ranks, axis_name)      # phase 1: translate
+    with jax.named_scope("translate"):          # phase 1
+        rows_per_shard = rank_of.shape[0]
+        shard_id = jax.lax.axis_index(axis_name)
+        local = indices - shard_id * rows_per_shard
+        ok = (local >= 0) & (local < rows_per_shard)
+        clamped = jnp.clip(local, 0, rows_per_shard - 1)
+        ranks = jnp.where(ok, jnp.take(rank_of, clamped, axis=0), 0)
+        ranks = jax.lax.psum(ranks, axis_name)
     return sharded_embedding_bag(table, ranks, axis_name, mode,
                                  scatter=scatter)

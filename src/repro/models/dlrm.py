@@ -115,14 +115,18 @@ def _bag(params, indices, t: int, mesh, axes, hybrid: bool = False,
     ``hybrid=True`` finishes with psum_scatter: bags come back with the
     batch split over (axes x model). ``table_2d=True`` additionally shards
     table rows over (model x data) — no table replication across data, so
-    no dense table-grad all-reduce (§Perf H3).
+    no dense table-grad all-reduce. The translation runs under the named
+    scope ``translate`` and the pooled lookup under ``sls`` (on the sharded
+    paths, inside ``repro.embedding.sharded``).
     """
     table = params["tables"][t]
     if mesh is None:
         idx = indices
         if "rank_of" in params:
-            idx = jnp.take(params["rank_of"][t], idx, axis=0)
-        return embedding_bag_dense(table, idx)
+            with jax.named_scope("translate"):
+                idx = jnp.take(params["rank_of"][t], idx, axis=0)
+        with jax.named_scope("sls"):
+            return embedding_bag_dense(table, idx)
     from jax.sharding import PartitionSpec as P
     from repro.embedding.sharded import (sharded_embedding_bag,
                                          sharded_embedding_bag_2d,
@@ -171,19 +175,26 @@ def forward(params, batch, cfg: DLRMConfig, mesh=None, axes=("data",),
     ``hybrid`` splits the batch across (axes x model) for the dense path
     (bottom/top MLP + interaction): the bag psum becomes a psum_scatter
     (half the wire) and the dense compute uses all chips instead of
-    running model-ways replicated — §Perf H3.
+    running model-ways replicated.
+
+    Each layer runs under one named scope: ``translate`` and ``sls`` (see
+    ``_bag``), ``interact``, and ``mlp`` for both MLPs. A scope only names
+    the ops in the compiled program's metadata; it adds no op.
     """
     hybrid = hybrid and mesh is not None and axes is not None
-    dense_in = batch["dense"]
-    if hybrid:
-        dense_in = _constrain_hybrid(dense_in, mesh, axes)
-    x = mlp(params["bot"], dense_in)
+    with jax.named_scope("mlp"):
+        dense_in = batch["dense"]
+        if hybrid:
+            dense_in = _constrain_hybrid(dense_in, mesh, axes)
+        x = mlp(params["bot"], dense_in)
     bags = [_bag(params, batch["indices"][:, t, :], t, mesh, axes, hybrid,
                  table_2d=hybrid and table_2d)
             for t in range(cfg.n_tables)]
-    bags = jnp.stack(bags, axis=1)
-    feat = interact(x, bags, cfg.interaction)
-    return mlp(params["top"], feat)[:, 0]          # logits (B,)
+    with jax.named_scope("interact"):
+        bags = jnp.stack(bags, axis=1)
+        feat = interact(x, bags, cfg.interaction)
+    with jax.named_scope("mlp"):
+        return mlp(params["top"], feat)[:, 0]      # logits (B,)
 
 
 def loss(params, batch, cfg: DLRMConfig, mesh=None, axes=("data",),
@@ -209,16 +220,19 @@ def retrieval_score(params, batch, cfg: DLRMConfig, mesh=None,
     the last sparse field is swept over ``candidates`` (N,) ids — a batched
     interaction + top-MLP over N rows, no loop.
     """
-    x = mlp(params["bot"], batch["dense"])                      # (1, D)
+    with jax.named_scope("mlp"):
+        x = mlp(params["bot"], batch["dense"])                  # (1, D)
     fixed = [_bag(params, batch["indices"][:, t, :], t, mesh, None)
              for t in range(cfg.n_tables - 1)]                  # batch 1
     cand = _bag(params, batch["candidates"][:, None],
                 cfg.n_tables - 1, mesh, axes)                   # (N, D)
     n = cand.shape[0]
-    bags = jnp.concatenate(
-        [jnp.broadcast_to(jnp.stack(fixed, 1), (n, cfg.n_tables - 1,
-                                                cfg.embed_dim)),
-         cand[:, None, :]], axis=1)
-    xb = jnp.broadcast_to(x, (n, cfg.embed_dim))
-    feat = interact(xb, bags, cfg.interaction)
-    return mlp(params["top"], feat)[:, 0]                       # (N,)
+    with jax.named_scope("interact"):
+        bags = jnp.concatenate(
+            [jnp.broadcast_to(jnp.stack(fixed, 1), (n, cfg.n_tables - 1,
+                                                    cfg.embed_dim)),
+             cand[:, None, :]], axis=1)
+        xb = jnp.broadcast_to(x, (n, cfg.embed_dim))
+        feat = interact(xb, bags, cfg.interaction)
+    with jax.named_scope("mlp"):
+        return mlp(params["top"], feat)[:, 0]                   # (N,)
