@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.embedding.bag import LANES
+
 
 @dataclasses.dataclass
 class RemapSpec:
@@ -77,6 +79,48 @@ class RemapSpec:
 def remap_table(table: jax.Array, spec: RemapSpec) -> jax.Array:
     """Materialise the stored (rank-ordered) table from the logical one."""
     return jnp.take(table, jnp.asarray(spec.perm), axis=0)
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class PackedRanks:
+    """The ``rank_of`` hash tables of ``tables`` tables stored lane-dense, as
+    one array of 128-lane lines: entry ``i`` of table ``t`` is element
+    ``t*rows + i`` of ``lines`` flattened, and entries past a shorter
+    table's end are zeros. ``translate`` looks up every table in one gather
+    of lines; iterating gives each table's logical ``(rows,)`` array.
+
+    A TPU step given one 4-MB ``rank_of`` array per table copies each into
+    fast memory every step, eight device ops a table, and given them
+    stacked as ``(tables, rows)`` it gathers single int32 entries from
+    HBM, twice as slowly."""
+
+    lines: jax.Array
+    tables: int = dataclasses.field(metadata={"static": True})
+    rows: int = dataclasses.field(metadata={"static": True})
+
+    @classmethod
+    def stack(cls, rank_ofs: list[np.ndarray]) -> "PackedRanks":
+        """From each table's host ``rank_of`` array, onto the device."""
+        rows = max(r.size for r in rank_ofs)
+        flat = np.zeros(-(-len(rank_ofs) * rows // LANES) * LANES, np.int32)
+        for t, r in enumerate(rank_ofs):
+            flat[t * rows:t * rows + r.size] = r
+        return cls(jnp.asarray(flat.reshape(-1, LANES)), len(rank_ofs), rows)
+
+    def __iter__(self):
+        flat = self.lines.reshape(-1)
+        return iter([flat[t * self.rows:(t + 1) * self.rows]
+                     for t in range(self.tables)])
+
+    def translate(self, indices: jax.Array) -> jax.Array:
+        """Ranks of the logical ids ``indices`` (..., tables, L): line
+        ``id // 128`` of each, then its lane."""
+        flat = indices + (self.rows * jnp.arange(self.tables,
+                                                 dtype=indices.dtype))[:, None]
+        got = jnp.take(self.lines, flat >> (LANES.bit_length() - 1), axis=0)
+        lane = (flat & (LANES - 1))[..., None]
+        return jnp.where(lane == jnp.arange(LANES), got, 0).sum(axis=-1)
 
 
 def translate(indices: jax.Array, spec: RemapSpec) -> jax.Array:

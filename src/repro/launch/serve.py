@@ -7,10 +7,11 @@ lane per NAND access policy, each lane ``--channels`` concurrent SLS
 servers — so the identical stream is replayed against RecSSD / RM-SSD /
 RecFlash and per-request p50/p95/p99 latency and throughput come out per
 policy (DESIGN.md §3). Then the device half scores the RecFlash lane's
-batches through the jitted DLRM forward (tables stored frequency-remapped,
-logical ids translated via the rank_of hash table), padded to a single
-shape that is compiled before the timed loop. Tables that do not fit the
-device are an error, not a skipped half; ``--skip-compute`` opts out.
+batches through the jitted DLRM forward (tables stored frequency-remapped
+and lane-dense, logical ids translated via the rank_of hash table), padded
+to a single shape that is compiled before the timed loop. Tables that do
+not fit the device are an error, not a skipped half; ``--skip-compute``
+opts out.
 
     PYTHONPATH=src python -m repro.launch.serve --requests 200 --batch 64
 """
@@ -27,7 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.models.dlrm as dlrm
-from repro.embedding.layout import RemapSpec, remap_table
+from repro.embedding.bag import pack_table
+from repro.embedding.layout import PackedRanks, RemapSpec
 from repro.flashsim.timeline import SERVING_POLICIES
 from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import (BatcherConfig, Deployment, DeploymentConfig,
@@ -65,24 +67,40 @@ def check_fits(need_bytes: int, device) -> None:
             f"are free; cut the tables with --rows or pass --skip-compute")
 
 
+@jax.jit
+def _store(table: jax.Array, perm: jax.Array):
+    """One table as it is served: its rows in rank order (``remap_table``),
+    packed lane-dense where a 128-lane line holds several (``pack_table``).
+    One program, so the rank-ordered table is never held beside its packed
+    copy. ``perm`` is a permutation, so clipping changes no index; it
+    spares the out-of-range fill, 0.5 MB of the program's code, which the
+    device holds beside the tables."""
+    return pack_table(jnp.take(table, perm, axis=0, mode="clip"))
+
+
 def place_tables(cfg: dlrm.DLRMConfig, specs: list[RemapSpec], seed: int):
     """Initialise the model on the device and store every table
-    frequency-remapped. Tables are remapped one at a time and each original
-    is released before the next, so peak memory stays near one copy."""
+    frequency-remapped and lane-dense (``_store``), so the step gathers from
+    the tables in place. Tables are stored one at a time and each original
+    is released before the next, so peak memory stays near one copy. The
+    rank_of hash tables are stored lane-dense too, as one ``PackedRanks``."""
     params = dlrm.init(jax.random.PRNGKey(seed), cfg)
     tables = params["tables"]
     for t, spec in enumerate(specs):
-        tables[t] = remap_table(tables[t], spec).block_until_ready()
-    rank_ofs = [jnp.asarray(s.rank_of, jnp.int32) for s in specs]
-    return params, rank_ofs
+        tables[t] = jax.block_until_ready(
+            _store(tables[t], jnp.asarray(spec.perm)))
+    return params, PackedRanks.stack([s.rank_of for s in specs])
 
 
 @functools.partial(jax.jit, static_argnames="cfg")
-def serve_step(params, rank_ofs, batch, cfg: dlrm.DLRMConfig):
-    """The served step: DLRM forward over frequency-remapped tables. The
-    ``rank_ofs`` hash tables are arguments, so no table-sized constant is
-    baked into the program."""
-    return dlrm.forward(dlrm.add_remap(params, rank_ofs), batch, cfg)
+def serve_step(params, ranks: PackedRanks, batch, cfg: dlrm.DLRMConfig):
+    """The served step: every table's logical ids translated to ranks in one
+    gather (scope ``translate``), then the DLRM forward over the
+    frequency-remapped tables. The hash tables are an argument, so no
+    table-sized constant is baked into the program."""
+    with jax.named_scope("translate"):
+        idx = ranks.translate(batch["indices"])
+    return dlrm.forward(params, {**batch, "indices": idx}, cfg)
 
 
 def _pad(x: np.ndarray, rows: int) -> np.ndarray:

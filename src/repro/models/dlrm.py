@@ -19,7 +19,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.compat import shard_map
-from repro.embedding.bag import embedding_bag_dense
+from repro.embedding.bag import (PackedTable, embedding_bag_dense,
+                                embedding_bag_packed)
 from repro.models.common import mlp, mlp_init
 
 
@@ -117,7 +118,8 @@ def _bag(params, indices, t: int, mesh, axes, hybrid: bool = False,
     table rows over (model x data) — no table replication across data, so
     no dense table-grad all-reduce. The translation runs under the named
     scope ``translate`` and the pooled lookup under ``sls`` (on the sharded
-    paths, inside ``repro.embedding.sharded``).
+    paths, inside ``repro.embedding.sharded``). A table stored lane-dense
+    (``PackedTable``, the served tables) is read by the packed gather.
     """
     table = params["tables"][t]
     if mesh is None:
@@ -126,6 +128,8 @@ def _bag(params, indices, t: int, mesh, axes, hybrid: bool = False,
             with jax.named_scope("translate"):
                 idx = jnp.take(params["rank_of"][t], idx, axis=0)
         with jax.named_scope("sls"):
+            if isinstance(table, PackedTable):
+                return embedding_bag_packed(table.lines, idx, table.dim)
             return embedding_bag_dense(table, idx)
     from jax.sharding import PartitionSpec as P
     from repro.embedding.sharded import (sharded_embedding_bag,

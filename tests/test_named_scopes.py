@@ -24,6 +24,22 @@ CFG = dlrm.DLRMConfig(name="small", n_tables=3, n_dense=13, embed_dim=16,
                       top_mlp=(32, 16))
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _no_persistent_cache():
+    """The scoped and unscoped programs differ in metadata alone, which the
+    persistent cache's key leaves out: where an earlier test of the same
+    process turned the cache on (the benchmark's harness does), the
+    unscoped compile would load the scoped program."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
 def _shapes(batch: int = 8):
     params = jax.eval_shape(lambda: dlrm.init(jax.random.PRNGKey(0), CFG))
     rank_ofs = [jax.ShapeDtypeStruct((n,), jnp.int32) for n in CFG.n_rows]

@@ -11,6 +11,7 @@ this file. All compiles happen in the test's own process.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,9 +22,11 @@ from jax.sharding import PartitionSpec as P
 
 import repro.models.dlrm as dlrm
 from repro.configs.dlrm_rm2 import CONFIG as RM2
+from repro.embedding.bag import pack_table
+from repro.embedding.layout import PackedRanks
 from repro.kernels.dot_interaction import dot_interaction
 from repro.kernels.recflash_sls import recflash_sls
-from repro.launch.serve import serve_step
+from repro.launch.serve import _store, serve_step
 
 HBM_BYTES = 16e9            # one v5e chip
 BATCH = 64
@@ -77,6 +80,8 @@ def test_dot_interaction_compiles(one_chip, dim):
 
 
 def _rm2_shapes():
+    """dlrm_rm2's parameters with logical (V, D) tables, its rank_of hash
+    tables and a padded batch, as shapes."""
     cfg = RM2
     params = jax.eval_shape(lambda: dlrm.init(jax.random.PRNGKey(0), cfg))
     rank_ofs = [jax.ShapeDtypeStruct((n,), jnp.int32) for n in cfg.n_rows]
@@ -87,13 +92,32 @@ def _rm2_shapes():
     return cfg, params, rank_ofs, batch
 
 
-def test_served_forward_fits_one_chip(one_chip):
-    """The served step at dlrm_rm2 width (26 x 1M x 64 f32, 80 lookups)
-    fits one chip, and its rank_of hash tables are arguments, not
-    table-length constants."""
+def _served_step(one_chip, placed: bool):
+    """The served step at dlrm_rm2 width, lowered on the tables and rank_of
+    hash tables as ``serve.place_tables`` stores them; or the step as it was
+    before they were packed, on logical (V, D) tables and one rank_of array
+    per table."""
     cfg, params, rank_ofs, batch = _rm2_shapes()
-    lowered = serve_step.lower(_on(params, one_chip), _on(rank_ofs, one_chip),
-                               _on(batch, one_chip), cfg=cfg)
+    step = serve_step
+    if placed:
+        params = {**params, "tables": [jax.eval_shape(pack_table, t)
+                                       for t in params["tables"]]}
+        rows = max(cfg.n_rows)
+        rank_ofs = PackedRanks(jax.ShapeDtypeStruct(
+            (-(-cfg.n_tables * rows // 128), 128), jnp.int32),
+            cfg.n_tables, rows)
+    else:
+        step = jax.jit(lambda p, r, b, cfg: dlrm.forward(
+            dlrm.add_remap(p, r), b, cfg), static_argnames="cfg")
+    return cfg, step.lower(_on(params, one_chip), _on(rank_ofs, one_chip),
+                           _on(batch, one_chip), cfg=cfg)
+
+
+def test_served_forward_fits_one_chip(one_chip):
+    """The served step at dlrm_rm2 width (26 x 1M x 64 f32, 80 lookups), on
+    the tables as they are placed, fits one chip, and its rank_of hash
+    tables are arguments, not table-length constants."""
+    cfg, lowered = _served_step(one_chip, placed=True)
     assert not [line for line in lowered.as_text().splitlines()
                 if "dense<" in line and f"{cfg.n_rows[0]}x" in line]
     mem = lowered.compile().memory_analysis()
@@ -101,6 +125,65 @@ def test_served_forward_fits_one_chip(one_chip):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.argument_size_in_bytes > 26 * 1_000_000 * 64 * 4
     assert total < HBM_BYTES
+
+
+_COPY = re.compile(r"= (\w+)\[([\d,]+)\]\S* copy(?:-start)?\(")
+_ENTRY_OP = re.compile(r"^\s+(?:ROOT )?%\S+ = .*?\s([a-z][\w-]*)\(")
+_NOT_RUN = ("parameter", "get-tuple-element", "tuple", "bitcast", "constant")
+
+
+def _table_copies(hlo_text: str, table_elems: int) -> list[str]:
+    """The copy instructions whose result holds at least one table."""
+    return [line for line in hlo_text.splitlines()
+            if (m := _COPY.search(line))
+            and np.prod([int(d) for d in m.group(2).split(",")])
+            >= table_elems]
+
+
+def _device_ops(hlo_text: str) -> list[str]:
+    """The opcodes of the entry computation's instructions that run on the
+    device: one device-trace event each, every step."""
+    entry = hlo_text[hlo_text.index("\nENTRY"):]
+    return [m.group(1) for line in entry.splitlines()[1:]
+            if (m := _ENTRY_OP.match(line)) and m.group(1) not in _NOT_RUN]
+
+
+@pytest.mark.parametrize("placed", [True, False])
+def test_served_step_gathers_from_the_tables_in_place(one_chip, placed):
+    """On lane-dense (500k, 128) tables the compiled step copies no table
+    and needs under 128 MB of temporaries, of which 68 MB hold the rank_of
+    lines its translation gathers. On (1M, 64) tables, in the TPU's default
+    column-major layout, it copies every table to row-major before its
+    gather, each step: that is why ``place_tables`` packs them.
+
+    The placed step also runs at most 400 device ops, one profiler event
+    each a step: a v5e profile of a 51-s window dropped its events past
+    ~4.5M ops, at ~7,500 of ~10,700 steps of 599 ops (one rank_of array per
+    table, each copied into fast memory every step)."""
+    cfg, lowered = _served_step(one_chip, placed)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    copies = _table_copies(text, cfg.n_rows[0] * cfg.embed_dim)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if placed:
+        assert copies == []
+        assert temp < 128e6
+        assert len(_device_ops(text)) <= 400
+    else:
+        assert len(copies) == cfg.n_tables
+        assert temp > 2 * cfg.n_rows[0] * cfg.embed_dim * 4
+
+
+@pytest.mark.parametrize("dim", [32, 64])
+def test_table_placement_is_a_small_program(one_chip, dim):
+    """``place_tables`` remaps and packs each 1M-row table in one program
+    of under 1 MB of code, which the device holds beside the tables. (With
+    the packing spelled as a plain reshape, the TPU compiler takes a minute
+    or two and makes ~33 MB of code.)"""
+    compiled = _store.lower(_sds((1_000_000, dim), jnp.float32, one_chip),
+                            _sds((1_000_000,), jnp.int32, one_chip)
+                            ).compile()
+    assert compiled.memory_analysis().generated_code_size_in_bytes < 1e6
 
 
 def test_row_sharded_forward_compiles_on_four_chips(topo):
