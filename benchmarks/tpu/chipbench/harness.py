@@ -3,14 +3,16 @@
 One run serves one cell of ``BENCHMARK.json`` once. The cell names a
 configuration file (``configs/<config>.json``) and a traffic file
 (``traffic/<traffic>.json``); each per-layer metric is a reader in
-``metrics/<name>.py``. Nothing here names a cell, a configuration or a
-metric.
+``metrics/<name>.py``, and the configuration names its plain reference, a
+module ``references/<reference>.py``. Nothing here names a cell, a
+configuration, a model or a metric.
 
-Set-up builds what ``repro.launch.serve.main`` builds: the deployment's
-offline phase (``Deployment(...).stats``, with no NAND lane), a
-``RemapSpec`` per table, ``serve.place_tables``, and ``serve.serve_step``
-compiled once at the padded ``max_batch`` shape. Then it draws the request
-pool and runs a few warm-up steps.
+Set-up draws the request pool at the file's per-table sizes, then builds
+what ``repro.launch.serve.main`` builds (``program.build``): the
+deployment's offline phase (``Deployment(...).stats``, with no NAND lane),
+a ``RemapSpec`` per table, ``serve.place_tables``, and ``serve.serve_step``
+compiled once at the padded ``max_batch`` rows of the pool's index shape.
+Then it runs a few warm-up steps.
 
 The window offers the traffic's requests on the real clock. The program's
 ``DynamicBatcher.next_span`` decides each dispatch; the device is free again
@@ -20,7 +22,7 @@ arrivals after ``--seconds``; requests due in it are waited for, for at most
 ``DRAIN_S`` more, and one that never completes is failed.
 
 After the window the program's state is freed and every served logit is
-compared with the plain reference (``reference.py``) on the un-remapped
+compared with the configuration's plain reference on the un-remapped
 tables; ``correct`` needs every request served and the widest gap within the
 configuration's limit.
 """
@@ -40,7 +42,9 @@ import time
 from pathlib import Path
 
 import numpy as np
-from chipbench import reference, trace, yardstick
+from chipbench import config, program, trace, yardstick
+from chipbench.config import BenchError
+from chipbench.reference import NOT_A_NUMBER, logit_gap
 from chipbench.traffic import Traffic, make_pool, make_schedule
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
@@ -51,10 +55,6 @@ WARMUP_STEPS = 3
 DRAIN_S = 60.0
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                   "/jax/core/compile/backend_compile_duration")
-
-
-class BenchError(RuntimeError):
-    """A run that cannot be made: no chip, a bad cell, a missing file."""
 
 
 def process_age_s() -> float:
@@ -147,17 +147,46 @@ class Run:
     def fills(self) -> np.ndarray:
         return np.array([b.size for b in self.batches], np.float64)
 
+    def _traced(self) -> tuple[tuple[float, float] | None, list | None]:
+        """The traced window and the device ``(start, end)`` of the step of
+        each dispatch in it, in ns.
+
+        Where the trace holds one step execution per dispatch, that is the
+        harness's whole window and every dispatch. The profiler keeps a
+        fixed number of device events and drops those after it, so a window
+        of more dispatches holds the steps of its first ones only: then the
+        traced window ends with the last step whose every op was kept, and
+        the steps are those of the first dispatches. No steps where the
+        trace holds more step executions than dispatches, or none. Kept for
+        the readers while ``trace`` and ``batches`` stay the same."""
+        key = (self.trace, len(self.batches))
+        memo = getattr(self, "_traced_memo", None)
+        if memo is None or memo[0][0] is not key[0] or memo[0][1] != key[1]:
+            self._traced_memo = memo = (key, self._cut())
+        return memo[1]
+
+    def _cut(self) -> tuple[tuple[float, float] | None, list | None]:
+        win = None if self.trace is None else trace.window(self.trace)
+        if win is None:
+            return None, None
+        found = trace.steps(self.trace, *win)
+        if len(found) == len(self.batches):
+            return win, found
+        last_op = max((s + d for _, s, d in self.trace["ops"]), default=0)
+        kept = [step for step in found if step[1] <= last_op]
+        if not kept or len(found) > len(self.batches):
+            return win, None
+        return (win[0], kept[-1][1]), kept
+
     def window(self) -> tuple[float, float] | None:
-        return None if self.trace is None else trace.window(self.trace)
+        return self._traced()[0]
 
     def steps(self) -> list[tuple[float, float]] | None:
-        """Device ``(start, end)`` of each dispatch's step, in ns, where the
-        trace holds exactly one step execution per dispatch."""
-        win = self.window()
-        if win is None:
-            return None
-        found = trace.steps(self.trace, *win)
-        return found if len(found) == len(self.batches) else None
+        """Device ``(start, end)`` of the step of each dispatch in the traced
+        window, in ns: of every dispatch, or of the first ones (``_traced``).
+        A reader that pairs steps with dispatches takes the first
+        ``len(steps)`` of ``batches``."""
+        return self._traced()[1]
 
 
 class _CompileCounter:
@@ -194,11 +223,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     import jax.numpy as jnp
     from jax.profiler import TraceAnnotation
 
-    from repro.embedding.layout import RemapSpec
     from repro.launch import serve
     from repro.launch.compile_cache import enable_compile_cache
-    from repro.serving import (BatcherConfig, Deployment, DeploymentConfig,
-                               DynamicBatcher, arch_model_config)
+    from repro.serving import BatcherConfig, DynamicBatcher
 
     parts: dict[str, float] = {"start": process_age_s()}
     mark = time.perf_counter()
@@ -210,6 +237,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         mark = now
 
     cfg, traffic = cell.cfg, cell.traffic
+    reference = config.reference(cfg)
     dev = tpu_device(cell.chips) if require_tpu else jax.devices()[0]
     peaks = yardstick.load_peaks(BENCH_DIR / "peaks.json", dev.device_kind) \
         if require_tpu else None
@@ -219,48 +247,18 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     jax.monitoring.register_event_duration_secs_listener(counter)
     lap("import")
 
-    # --- the deployment, as serve.main builds it, with no NAND lane ------
-    max_batch, max_wait_us = cfg["max_batch"], cfg["max_wait_us"]
-    dep_cfg = DeploymentConfig.from_arch(
-        cfg["arch"], n_rows=cfg["n_rows"], k=cfg["k"], policies=(),
-        sample_inferences=cfg["sample_inferences"],
-        batcher=BatcherConfig(max_batch=max_batch, max_wait_us=max_wait_us))
-    model = arch_model_config(dep_cfg)
-    as_run = {"n_tables": model.n_tables, "n_dense": model.n_dense,
-              "embed_dim": model.embed_dim, "n_rows": model.n_rows[0],
-              "lookups": model.lookups, "bot_mlp": list(model.bot_mlp),
-              "top_mlp": list(model.top_mlp),
-              "interaction": model.interaction}
-    differ = {k: (cfg[k], v) for k, v in as_run.items() if cfg[k] != v}
-    if differ or set(model.n_rows) != {cfg["n_rows"]}:
-        raise BenchError(f"the program runs {cfg['arch']} with other sizes "
-                         f"than {cfg['name']}'s file states: {differ}")
-    dep = Deployment(dep_cfg)
-    specs = [RemapSpec.from_counts(s.counts) for s in dep.stats]
-    del dep
-    lap("offline_phase")
-
-    wseed = weight_seed(seed)
-    serve.check_fits(model.n_tables * cfg["n_rows"] * (model.embed_dim + 1) * 4,
-                     dev)
-    params, rank_ofs = serve.place_tables(model, specs, wseed)
-    jax.block_until_ready((params, rank_ofs))
-    del specs
-    lap("tables")
-
-    pool_idx, pool_dense = make_pool(traffic, model.n_tables, cfg["n_rows"],
-                                     model.lookups, model.n_dense, seed)
+    pool_idx, pool_dense = make_pool(
+        traffic, config.per_table(cfg, "n_rows"),
+        config.per_table(cfg, "lookups"), cfg["n_dense"], seed)
     arrival_us, pool_ids = make_schedule(traffic, seconds, seed)
     lap("pool")
 
-    shape = {"dense": jax.ShapeDtypeStruct((max_batch, model.n_dense),
-                                           jnp.float32),
-             "indices": jax.ShapeDtypeStruct(
-                 (max_batch, model.n_tables, model.lookups), jnp.int32)}
-    with jax.default_matmul_precision(cfg["matmul_precision"]):
-        step = serve.serve_step.lower(params, rank_ofs, shape,
-                                      cfg=model).compile()
-    lap("compile")
+    # --- the deployment, as serve.main builds it, with no NAND lane ------
+    max_batch, max_wait_us = cfg["max_batch"], cfg["max_wait_us"]
+    wseed = weight_seed(seed)
+    prog = program.build(cfg, pool_idx.shape[1:], wseed, lap=lap)
+    step, params, rank_ofs = prog.step, prog.params, prog.ranks
+    del prog
 
     for w in range(WARMUP_STEPS):
         ids = np.arange(w * max_batch, (w + 1) * max_batch) % traffic.pool
@@ -345,8 +343,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     want = np.full(traffic.pool, np.nan, np.float32)
     want[used] = reference.logits(cfg, wseed, pool_idx[used],
                                   pool_dense[used])
-    gap = reference.logit_gap(served[ok], want[pool_ids[ok]]) \
-        if n_done else reference.NOT_A_NUMBER
+    gap = logit_gap(served[ok], want[pool_ids[ok]]) \
+        if n_done else NOT_A_NUMBER
     checks = {"logit_gap": {"value": gap, "limit": cfg["logit_gap_limit"]},
               "unserved": {"value": n - n_done, "limit": 0}}
     correct = all(c["value"] <= c["limit"] for c in checks.values())

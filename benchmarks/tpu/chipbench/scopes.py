@@ -8,8 +8,8 @@ instruction to scope comes from the compiled step's own HLO text
 (``op_scopes``).
 
 The harness frees its compiled step before the readers run, so ``of(run)``
-compiles the step once more, from the run's configuration file and as the
-harness compiles it, on tables placed as the harness places them, and keeps
+compiles the step once more, from the run's configuration file and at its
+pool's index shape, through the harness's own ``program.build``, and keeps
 the map on the run. One HLO module compiles to the same instructions under
 the same names, so the map names the ops that ran; an op of the run's steps
 that the map does not hold makes every reading null. A program without the
@@ -20,10 +20,11 @@ from __future__ import annotations
 
 import bisect
 import collections
+import contextlib
 import re
 
 import numpy as np
-from chipbench import trace, yardstick
+from chipbench import config, program, trace, yardstick
 
 SCOPES = ("translate", "sls", "interact", "mlp")
 
@@ -137,47 +138,37 @@ def unscoped_ns(tr: dict, steps: list[tuple[float, float]],
 
 
 # ------------------------------------------------- the run's compiled step
-def step_hlo(cfg: dict) -> str:
-    """The optimised HLO text of the served step of the configuration file
-    ``cfg``, compiled as the harness compiles it: ``serve.serve_step`` on
-    tables that ``serve.place_tables`` placed, at the padded ``max_batch``
-    shape and the file's matmul precision, on the default device.
-
-    The tables are placed for their layout on the device, which decides
-    the step's relayout copies; their values do not matter, and they are
-    freed on return. The persistent compile cache keys on the module
-    without its metadata by default, so the harness's executable may hold
-    the op names of another program with the same ops that put it there
-    first. So this compile starts from cleared in-memory caches, which
-    would give that executable back, and keys the persistent cache on the
-    metadata too."""
+@contextlib.contextmanager
+def _fresh_compile():
+    """A compile that no in-memory cache answers and whose persistent cache
+    key holds the metadata (``step_hlo``)."""
     import jax
-    import jax.numpy as jnp
 
-    from repro.embedding.layout import RemapSpec
-    from repro.launch import serve
-    from repro.serving import DeploymentConfig, arch_model_config
-
-    model = arch_model_config(DeploymentConfig.from_arch(
-        cfg["arch"], n_rows=cfg["n_rows"], policies=()))
-    specs = [RemapSpec.from_counts(np.zeros(n, np.int64))
-             for n in model.n_rows]
-    params, rank_ofs = serve.place_tables(model, specs, 0)
-    shape = {"dense": jax.ShapeDtypeStruct((cfg["max_batch"], model.n_dense),
-                                           jnp.float32),
-             "indices": jax.ShapeDtypeStruct(
-                 (cfg["max_batch"], model.n_tables, model.lookups),
-                 jnp.int32)}
     key = "jax_compilation_cache_include_metadata_in_key"
     was = getattr(jax.config, key)
     jax.clear_caches()
     jax.config.update(key, True)
     try:
-        with jax.default_matmul_precision(cfg["matmul_precision"]):
-            return serve.serve_step.lower(params, rank_ofs, shape,
-                                          cfg=model).compile().as_text()
+        yield
     finally:
         jax.config.update(key, was)
+
+
+def step_hlo(cfg: dict, index_shape: tuple) -> str:
+    """The optimised HLO text of the served step of the configuration file
+    ``cfg`` for requests of ``index_shape``, compiled as the harness
+    compiles it (``program.build``), on the default device.
+
+    The tables are placed for their layout on the device, which decides
+    the step's relayout copies; their values do not matter (the remap of
+    zero counts, weight seed 0), and they are freed on return. The
+    persistent compile cache keys on the module without its metadata by
+    default, so the harness's executable may hold the op names of another
+    program with the same ops that put it there first. So this compile
+    starts from cleared in-memory caches, which would give that executable
+    back, and keys the persistent cache on the metadata too."""
+    return program.build(cfg, index_shape, 0, offline=False,
+                         compile_scope=_fresh_compile).step.as_text()
 
 
 def of(run) -> dict[str, str] | None:
@@ -189,7 +180,7 @@ def of(run) -> dict[str, str] | None:
         steps = run.steps()
         found = None
         if steps:
-            found = op_scopes(step_hlo(run.cfg))
+            found = op_scopes(step_hlo(run.cfg, run.pool_indices.shape[1:]))
             ran = {instruction(name) for ops in _ops_by_step(run.trace, steps)
                    for name, _, _ in ops}
             if not ran <= found.keys():
@@ -223,13 +214,8 @@ def unscoped_ms(run) -> float | None:
 # ------------------------------------------------------ the SLS's roofline
 def sls_least_time_s(cfg: dict, indices: np.ndarray, peaks: dict) -> float:
     """The least time the chip could take for the SLS of the real rows
-    ``indices`` (``(rows, n_tables, lookups)``): each distinct (table, row)
-    read once, at ``embed_dim`` x 4 B, and one add per pooled element.
-
-    No ``rank_of`` reads and no padded rows, so no implementation of the
-    SLS (``jnp.take``, ``recflash_sls`` or a fused layout) can read above
-    100%."""
-    flops = indices.size * cfg["embed_dim"]
-    n_bytes = yardstick.distinct_rows(indices) * cfg["embed_dim"] \
-        * yardstick.F32
+    ``indices``: the work the configuration's reference counts
+    (``sls_work``: each distinct (table, row) read once, one add per pooled
+    element) over the chip's peaks."""
+    flops, n_bytes = config.reference(cfg).sls_work(cfg, indices)
     return yardstick.least_time_s(flops, n_bytes, peaks)[0]

@@ -23,6 +23,7 @@ import json
 from pathlib import Path
 
 import numpy as np
+from chipbench.config import columns, index_shape
 
 # copy of repro.data.tracegen.K_UNIQUE_RATE: locality knob -> unique-access rate
 K_UNIQUE_RATE = {0.0: 0.08, 0.3: 0.22, 0.8: 0.37, 1.0: 0.51, 2.0: 0.66}
@@ -87,40 +88,51 @@ class Traffic:
         return max(1, int(round(self.rate_rps * seconds)))
 
 
-def _zipf_ranks(rng: np.random.Generator, cdf: np.ndarray,
-                shape: tuple) -> np.ndarray:
-    """Inverse-CDF draws of ranks in ``[0, len(cdf))``: the ranks of
-    ``searchsorted(cdf, u, side="right")`` for uniform ``u``.
+def _zipf_sampler(n_rows: int, alpha: float):
+    """Inverse-CDF draws of Zipf(alpha) ranks in ``[0, n_rows)``: a function
+    of uniform ``u`` that gives ``searchsorted(cdf, u, side="right")``.
 
     A guide table holds the answer at each multiple of ``2**-_GUIDE_BITS``;
     where it is the same at both ends of ``u``'s bin, that is the answer, and
     only the other draws (the long tail) are searched in full.
     """
-    u = rng.random(shape)
+    cdf = np.cumsum(zipf_probs(n_rows, alpha))
     bins = 1 << _GUIDE_BITS
     guide = np.searchsorted(cdf, np.arange(bins + 1) / bins, side="right")
     settled = guide[:-1] == guide[1:]
-    j = (u * bins).astype(np.intp)
-    ranks = guide[j]
-    open_ = ~settled[j]
-    ranks[open_] = np.searchsorted(cdf, u[open_], side="right")
-    return np.minimum(ranks, cdf.size - 1)
+
+    def ranks(u: np.ndarray) -> np.ndarray:
+        j = (u * bins).astype(np.intp)
+        out = guide[j]
+        open_ = ~settled[j]
+        out[open_] = np.searchsorted(cdf, u[open_], side="right")
+        return np.minimum(out, n_rows - 1)
+    return ranks
 
 
-def make_pool(traffic: Traffic, n_tables: int, n_rows: int, lookups: int,
-              n_dense: int, seed: int) -> tuple[np.ndarray, np.ndarray]:
-    """``(indices (pool, n_tables, lookups) int32, dense (pool, n_dense)
-    float32)``: Zipf(alpha) ranks through each table's popularity
-    permutation, and dense features from N(0, 1)."""
+def make_pool(traffic: Traffic, n_rows: tuple[int, ...],
+              lookups: tuple[int, ...], n_dense: int, seed: int
+              ) -> tuple[np.ndarray, np.ndarray]:
+    """``(indices int32, dense (pool, n_dense) float32)``: for table ``t``,
+    ``lookups[t]`` Zipf(alpha) ranks of its ``n_rows[t]`` rows through its
+    popularity permutation, and dense features from N(0, 1). The indices
+    are laid out as ``config.index_shape(lookups)`` says.
+
+    The uniforms are drawn in one call, request by request and table by
+    table; each vocabulary's CDF is built once and freed after use (a
+    40M-row CDF is 320 MB of float64)."""
     rng = np.random.default_rng([seed, 0])
-    cdf = np.cumsum(zipf_probs(n_rows, traffic.alpha))
-    ranks = _zipf_ranks(rng, cdf, (traffic.pool, n_tables, lookups))
-    indices = np.empty(ranks.shape, np.int32)
-    for t in range(n_tables):
-        indices[:, t] = popularity_perm(n_rows, t, traffic.pop_seed)[
-            ranks[:, t]]
+    u = rng.random((traffic.pool, sum(lookups)))
+    indices = np.empty(u.shape, np.int32)
+    cols = columns(lookups)
+    for vocab in dict.fromkeys(n_rows):
+        ranks = _zipf_sampler(vocab, traffic.alpha)
+        for t in (t for t, n in enumerate(n_rows) if n == vocab):
+            indices[:, cols[t]] = popularity_perm(vocab, t, traffic.pop_seed)[
+                ranks(u[:, cols[t]])]
+        del ranks
     dense = rng.standard_normal((traffic.pool, n_dense), dtype=np.float32)
-    return indices, dense
+    return indices.reshape((traffic.pool,) + index_shape(lookups)), dense
 
 
 def make_schedule(traffic: Traffic, seconds: float, seed: int

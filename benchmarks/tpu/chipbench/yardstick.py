@@ -1,18 +1,9 @@
-"""The work a served DLRM step requires, and the chip's peaks.
+"""The chip's peaks, and the arithmetic of a least time, for any model.
 
-Kept with the benchmark, so that a change to the program cannot move the
-yardstick. The operation count is a copy of
-``repro.models.dlrm.DLRMConfig.flops_per_sample``, computed from the sizes
-in the configuration's file. The byte count is what any implementation of
-the step has to move between HBM and the core, once per step:
-
-* each distinct embedding row that the batch touches, once (nothing stays
-  in VMEM from one step to the next), at ``embed_dim`` x 4 B;
-* the MLP weights and biases;
-* the dense features and indices of the real rows in, their logits out.
-
-It leaves out the ``rank_of`` reads (a layout choice, not work the model
-requires) and the padded rows. So no implementation can read above 100%.
+The work a step requires is the model's own: each configuration's reference
+module (``references/<name>.py``) counts it, beside the mathematics it
+counts. Here are the peaks table, the least time a count allows, and the
+count of distinct rows that every embedding model's byte count starts from.
 """
 
 from __future__ import annotations
@@ -21,65 +12,13 @@ import json
 from pathlib import Path
 
 import numpy as np
-
-F32 = 4
-
-
-def top_in(cfg: dict) -> int:
-    n = cfg["n_tables"] + 1
-    if cfg["interaction"] == "dot":
-        return cfg["embed_dim"] + n * (n - 1) // 2
-    return n * cfg["embed_dim"]
+from chipbench.config import by_table
 
 
-def mlp_sizes(cfg: dict) -> tuple[tuple, tuple]:
-    """Layer widths of the bottom and the top MLP, inputs first."""
-    bot = (cfg["n_dense"],) + tuple(cfg["bot_mlp"])
-    if bot[-1] != cfg["embed_dim"]:
-        bot = bot + (cfg["embed_dim"],)
-    return bot, (top_in(cfg),) + tuple(cfg["top_mlp"]) + (1,)
-
-
-def flops_per_sample(cfg: dict) -> int:
-    """Forward FLOPs one request requires: 2 x MACs of the MLP layers, 2 x
-    ``embed_dim`` for each pair ``i < j`` of the dot interaction, and one
-    add per pooled element of the SLS.
-
-    The arithmetic of ``DLRMConfig.flops_per_sample``, less three counts
-    that are not required work: a bottom layer ``embed_dim -> embed_dim``
-    that the model does not have, the lower triangle and diagonal of the
-    interaction, and a multiply per pooled element.
-    """
-    bot, top = mlp_sizes(cfg)
-    f = sum(2 * a * b for sizes in (bot, top)
-            for a, b in zip(sizes[:-1], sizes[1:], strict=True))
-    if cfg["interaction"] == "dot":
-        n = cfg["n_tables"] + 1
-        f += n * (n - 1) * cfg["embed_dim"]
-    f += cfg["n_tables"] * cfg["lookups"] * cfg["embed_dim"]
-    return f
-
-
-def mlp_weight_bytes(cfg: dict) -> int:
-    return sum(F32 * (a * b + b) for sizes in mlp_sizes(cfg)
-               for a, b in zip(sizes[:-1], sizes[1:], strict=True))
-
-
-def distinct_rows(indices: np.ndarray) -> int:
-    """Distinct rows a batch touches, summed over tables.
-    ``indices`` is ``(rows, n_tables, lookups)``."""
-    return sum(int(np.unique(indices[:, t]).size)
-               for t in range(indices.shape[1]))
-
-
-def step_bytes(cfg: dict, indices: np.ndarray) -> int:
-    """Bytes one step must move for the real rows ``indices``."""
-    n = indices.shape[0]
-    return (distinct_rows(indices) * cfg["embed_dim"] * F32
-            + mlp_weight_bytes(cfg)
-            + n * cfg["n_dense"] * F32
-            + indices.size * F32
-            + n * F32)
+def distinct_rows(indices: np.ndarray, lookups: tuple[int, ...]) -> int:
+    """Distinct rows a batch touches, summed over tables: each table's
+    over its own columns of ``indices`` (``config.by_table``)."""
+    return sum(int(np.unique(ids).size) for ids in by_table(indices, lookups))
 
 
 def load_peaks(path: Path, device_kind: str) -> dict:

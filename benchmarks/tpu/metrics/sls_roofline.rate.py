@@ -15,7 +15,8 @@ def read(run):
     sls_ms = scopes.scope_ms(run, "sls")
     if sls_ms is None or run.peaks is None:
         return None
+    traced = run.batches[:len(run.steps())]
     least = sum(scopes.sls_least_time_s(run.cfg, run.pool_indices[ids],
                                         run.peaks)
-                for ids in run.batches)
-    return 100.0 * least / (sls_ms * 1e-3 * len(run.batches))
+                for ids in traced)
+    return 100.0 * least / (sls_ms * 1e-3 * len(traced))

@@ -1,7 +1,7 @@
 """The comparison that decides ``correct``, at a size a test run holds.
 
-Runs go through ``harness.run_cell`` on the CPU with the tables cut to
-5,000 rows and a small pool; every width is the configuration's. The look
+Runs go through ``harness.run_cell`` on the CPU with each table cut to at
+most 5,000 rows and a small pool; every width is the configuration's. The look
 for a chip is skipped and nothing else: the served step, the batcher, the
 real-clock window and the reference are the benchmark's own.
 """
@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from chipbench import harness, reference
+from chipbench import config, harness
+from chipbench.reference import logit_gap
 from chipbench.traffic import make_pool
 
 HERE = Path(__file__).resolve().parent
@@ -38,7 +39,8 @@ def _no_persistent_cache():
 def small_cell(name: str, rate: float = 400.0) -> harness.Cell:
     cell = harness.load_cell(name)
     return dataclasses.replace(
-        cell, cfg=dict(cell.cfg, n_rows=ROWS),
+        cell, cfg=dict(cell.cfg, n_rows=[
+            min(n, ROWS) for n in config.per_table(cell.cfg, "n_rows")]),
         traffic=dataclasses.replace(cell.traffic, pool=256, rate_rps=rate))
 
 
@@ -54,13 +56,15 @@ def test_control_fails_the_limit_and_the_reference_passes(name):
     limit on each seed; the reference against itself reads 0."""
     cell = small_cell(name)
     cfg = cell.cfg
+    reference = config.reference(cfg)
     for seed in (1, 2, 3):
-        idx, dense = make_pool(cell.traffic, cfg["n_tables"], ROWS,
-                               cfg["lookups"], cfg["n_dense"], seed)
+        idx, dense = make_pool(cell.traffic, config.per_table(cfg, "n_rows"),
+                               config.per_table(cfg, "lookups"),
+                               cfg["n_dense"], seed)
         want = reference.logits(cfg, seed, idx, dense)
         control = reference.logits(cfg, seed, idx, dense, "high3")
-        assert reference.logit_gap(want, want) == 0.0
-        assert reference.logit_gap(control, want) > cfg["logit_gap_limit"]
+        assert logit_gap(want, want) == 0.0
+        assert logit_gap(control, want) > cfg["logit_gap_limit"]
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -72,7 +76,7 @@ def test_sound_run_is_correct(name):
     assert list(result)[-1] == "checks"
 
 
-def _broken_step(kind: str):
+def _broken_step(kind: str, head):
     import repro.models.dlrm as dlrm
 
     @functools.partial(jax.jit, static_argnames="cfg")
@@ -87,8 +91,7 @@ def _broken_step(kind: str):
                     zip(params["tables"], rank_ofs, strict=True))], axis=1)
             bot, top = ([(p["w"], p["b"]) for p in params[k]]
                         for k in ("bot", "top"))
-            return reference._head(bot, top, batch["dense"], bags,
-                                   precision="high3")
+            return head(bot, top, batch["dense"], bags, precision="high3")
         if kind == "translation_skipped":
             return dlrm.forward(params, batch, cfg)
         out = dlrm.forward(dlrm.add_remap(params, rank_ofs), batch, cfg)
@@ -106,7 +109,8 @@ def _broken_step(kind: str):
 def test_broken_step_is_not_correct(kind, monkeypatch):
     from repro.launch import serve
 
-    monkeypatch.setattr(serve, "serve_step", _broken_step(kind))
+    head = config.reference(small_cell(CELLS[0]).cfg)._head
+    monkeypatch.setattr(serve, "serve_step", _broken_step(kind, head))
     result = run_small(CELLS[0])
     assert not result["correct"]
     gap = result["checks"]["logit_gap"]

@@ -5,7 +5,7 @@ import re
 from pathlib import Path
 
 import pytest
-from chipbench import harness
+from chipbench import config, harness, program
 from chipbench.traffic import Traffic
 
 HERE = Path(__file__).resolve().parent
@@ -44,12 +44,17 @@ def test_config_file_holds_what_runs(entry):
     assert cfg["name"] == entry["name"]
     assert set(entry["reduced"]) <= set(cfg) and len(entry["source"]) <= 200
     model = arch_model_config(DeploymentConfig.from_arch(cfg["arch"]))
-    assert (model.n_tables, model.n_dense, model.embed_dim, model.lookups,
+    assert (model.n_tables, model.n_dense, model.embed_dim,
             list(model.bot_mlp), list(model.top_mlp), model.interaction) \
         == (cfg["n_tables"], cfg["n_dense"], cfg["embed_dim"],
-            cfg["lookups"], cfg["bot_mlp"], cfg["top_mlp"],
-            cfg["interaction"])
-    assert set(model.n_rows) == {cfg["n_rows"]}
+            cfg["bot_mlp"], cfg["top_mlp"], cfg["interaction"])
+    assert tuple(model.n_rows) == config.per_table(cfg, "n_rows")
+    assert (model.lookups,) * model.n_tables \
+        == config.per_table(cfg, "lookups")
+    # and what the harness builds from the file is what the file states
+    assert program.deployment(cfg)[1] == model
+    assert config.table_dtype(cfg).name == cfg["dtype"]
+    assert callable(config.reference(cfg).logits)
     assert 0 < cfg["logit_gap_limit"] < 1
 
 

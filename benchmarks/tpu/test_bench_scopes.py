@@ -14,6 +14,7 @@ compiles run on the CPU with the tables cut to 5,000 rows; every width is
 the configuration's.
 """
 
+import dataclasses
 import functools
 import json
 import re
@@ -23,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from chipbench import harness, scopes, trace, yardstick
+from chipbench import config, harness, scopes, trace, yardstick
 
 HERE = Path(__file__).resolve().parent
 TRACE = json.loads((HERE / "testdata" / "trace_rm2_small.json").read_text()
@@ -31,6 +32,7 @@ TRACE = json.loads((HERE / "testdata" / "trace_rm2_small.json").read_text()
 SCOPED = json.loads((HERE / "testdata" / "trace_rm2_scoped.json").read_text())
 CFG = json.loads((HERE / "configs" / "dlrm_rm2.json").read_text())
 SMALL = dict(CFG, n_rows=5000)
+SHAPE = config.index_shape(config.per_table(CFG, "lookups"))
 PEAKS = yardstick.load_peaks(HERE / "peaks.json", "TPU v5 lite")
 EXISTING = ("p99_ms.rate", "batch_fill.rate", "host_gap_ms.rate",
             "step_device_ms.rate", "step_mfu.rate", "idle_share.rate")
@@ -52,7 +54,7 @@ def _no_persistent_cache():
 
 @functools.cache
 def _small_step_hlo() -> str:
-    return scopes.step_hlo(SMALL)
+    return scopes.step_hlo(SMALL, SHAPE)
 
 
 def _entry(hlo_text):
@@ -176,7 +178,7 @@ def test_the_compile_keys_the_cache_on_metadata(monkeypatch, was):
     monkeypatch.setattr(serve, "serve_step", Spy())
     jax.config.update(key, was)
     try:
-        assert "jit_serve_step" in scopes.step_hlo(SMALL)
+        assert "jit_serve_step" in scopes.step_hlo(SMALL, SHAPE)
         assert seen == ["cleared", True]
         assert getattr(jax.config, key) is was
     finally:
@@ -207,7 +209,8 @@ if sys.argv[3] == "without":
 with jax.default_matmul_precision(cfg["matmul_precision"]):
     step = serve.serve_step.lower(params, rank_ofs, shape, cfg=model).compile()
 print(json.dumps({"step": "/sls/" in step.as_text(),
-                  "readers": "/sls/" in scopes.step_hlo(cfg)}))
+                  "readers": "/sls/" in scopes.step_hlo(
+                      cfg, (cfg["n_tables"], cfg["lookups"]))}))
 """
 
 
@@ -282,11 +285,51 @@ def test_an_op_the_compiled_step_lacks_makes_every_reading_null():
 
 
 def test_a_trace_without_steps_reads_null_and_compiles_nothing(monkeypatch):
-    monkeypatch.setattr(scopes, "step_hlo", lambda cfg: pytest.fail(
+    monkeypatch.setattr(scopes, "step_hlo", lambda cfg, shape: pytest.fail(
         "compiled without steps to read"))
     _, run = _laid_out(["fusion.1"])
     run.trace = dict(run.trace, modules=[])
     assert all(harness.load_reader(m)(run) is None for m in NEW)
+
+
+def test_a_trace_cut_short_reads_the_dispatches_it_holds():
+    """The profiler keeps a fixed number of device events and drops the
+    rest, so a long window holds the steps of its first dispatches only.
+    The step readers then read those dispatches as a run of only them
+    reads, and the traced window ends with the last step whose every op
+    was kept; a trace that holds every step reads as before."""
+    text = _small_step_hlo()
+    work = [name for name, opcode in _entry(text) if opcode not in NO_WORK]
+    _, run = _laid_out(work, batches=4)
+    run = dataclasses.replace(run, waiting=np.ones(4, bool))
+    tr, n = run.trace, len(work)
+    # the third step's module was kept, its ops only in part; the fourth
+    # dispatch left nothing
+    cut = dataclasses.replace(run, trace=dict(
+        tr, modules=tr["modules"][:3], ops=tr["ops"][:2 * n + 1]))
+    head = dataclasses.replace(
+        run, batches=run.batches[:2], waiting=run.waiting[:2],
+        trace=dict(tr, modules=tr["modules"][:2], ops=tr["ops"][:2 * n]))
+    for r in (cut, head, run):
+        r.op_scopes = scopes.op_scopes(text)
+    lo = trace.window(tr)[0]
+    name, start, length = tr["modules"][1]
+    assert cut.steps() == head.steps() == [
+        (s, s + d) for _, s, d in tr["modules"][:2]]
+    assert cut.window() == (lo, start + length)
+    assert head.window() == run.window() == trace.window(tr)
+    assert len(run.steps()) == 4
+    for metric in ["step_device_ms.rate", "host_gap_ms.rate",
+                   "step_mfu.rate", *NEW]:
+        value = harness.load_reader(metric)(cut)
+        assert value is not None and value == pytest.approx(
+            harness.load_reader(metric)(head)), metric
+    busy = trace.busy_ns(cut.trace, *cut.window())
+    assert harness.load_reader("idle_share.rate")(cut) == pytest.approx(
+        100.0 * (1.0 - busy / (start + length - lo)))
+    # more step executions than dispatches: no steps, as before
+    extra = dataclasses.replace(run, batches=run.batches[:3])
+    assert extra.steps() is None and extra.window() == trace.window(tr)
 
 
 # ------------------------------------------------ the cut of a chip trace
@@ -401,7 +444,8 @@ def test_a_scope_no_op_resolves_to_reads_null():
 
 
 def test_sls_least_time_counts_distinct_rows_and_pooled_adds():
-    cfg = {"n_tables": 2, "embed_dim": 4, "lookups": 3}
+    cfg = {"name": "tiny", "reference": "dlrm", "dtype": "float32",
+           "n_tables": 2, "embed_dim": 4, "lookups": 3}
     indices = np.array([[[5, 5, 7], [1, 2, 3]],
                         [[7, 8, 5], [3, 3, 3]]])
     n_bytes = 6 * 4 * 4                 # 3 + 3 distinct rows x 4 x 4 B

@@ -6,6 +6,7 @@ from pathlib import Path
 
 import numpy as np
 import pytest
+from chipbench import config
 from chipbench import traffic as tr
 
 HERE = Path(__file__).resolve().parent
@@ -25,18 +26,27 @@ def test_alpha_is_the_deployments_offline_calibration(path):
     cfg = json.loads((HERE / "configs" /
                       f"{json.loads(path.read_text())['config']}.json")
                      .read_text())
-    draws = cfg["sample_inferences"] * cfg["lookups"]
-    want = tr.calibrate_alpha(cfg["n_rows"], draws, tr.K_UNIQUE_RATE[t.k])
+    n_rows = set(config.per_table(cfg, "n_rows"))
+    lookups = set(config.per_table(cfg, "lookups"))
+    if len(n_rows) > 1 or len(lookups) > 1:
+        # the offline sample's rule is for one vocabulary and one pooling;
+        # a file with several states how it set its exponent
+        assert 0 < t.alpha < 3 and json.loads(path.read_text())["alpha_from"]
+        return
+    (n_rows,), (lookups,) = n_rows, lookups
+    draws = cfg["sample_inferences"] * lookups
+    want = tr.calibrate_alpha(n_rows, draws, tr.K_UNIQUE_RATE[t.k])
     assert t.alpha == want
-    assert want == calibrate_alpha(cfg["n_rows"], draws,
+    assert want == calibrate_alpha(n_rows, draws,
                                    tr.K_UNIQUE_RATE[t.k])
 
 
 def test_pool_is_fixed_by_the_seed_and_not_by_rate_or_length():
     path = HERE / "traffic" / "rm2.zipf_hot_poisson.json"
-    a = tr.make_pool(_small(path), 3, 1000, 5, 4, BIG_SEED)
-    b = tr.make_pool(_small(path, rate_rps=7.0), 3, 1000, 5, 4, BIG_SEED)
-    c = tr.make_pool(_small(path), 3, 1000, 5, 4, BIG_SEED + 1)
+    sizes = ((1000,) * 3, (5,) * 3)
+    a = tr.make_pool(_small(path), *sizes, 4, BIG_SEED)
+    b = tr.make_pool(_small(path, rate_rps=7.0), *sizes, 4, BIG_SEED)
+    c = tr.make_pool(_small(path), *sizes, 4, BIG_SEED + 1)
     for x, y in zip(a, b, strict=True):
         np.testing.assert_array_equal(x, y)
     assert not np.array_equal(a[0], c[0])
@@ -72,8 +82,8 @@ def test_keys_follow_the_programs_popularity_convention():
 
 def test_split_inverse_cdf_equals_the_plain_one():
     cdf = np.cumsum(tr.zipf_probs(50_000, 1.1))
-    ranks = tr._zipf_ranks(np.random.default_rng(3), cdf, (20_000,))
     u = np.random.default_rng(3).random(20_000)
+    ranks = tr._zipf_sampler(50_000, 1.1)(u)
     want = np.minimum(np.searchsorted(cdf, u, side="right"), cdf.size - 1)
     np.testing.assert_array_equal(ranks, want)
     assert ranks.max() > 1000
