@@ -39,7 +39,9 @@ import shutil
 import sys
 import tempfile
 import time
+from collections.abc import Callable
 from pathlib import Path
+from typing import Any
 
 import numpy as np
 from chipbench import config, program, trace, yardstick
@@ -147,6 +149,28 @@ class Run:
     def fills(self) -> np.ndarray:
         return np.array([b.size for b in self.batches], np.float64)
 
+    def kept(self, name: str, make: Callable[[], Any]) -> Any:
+        """``make()``, made once for the readers and kept while ``trace``
+        and ``batches`` stay the same."""
+        memo = self.__dict__.get("_kept")
+        if memo is None or memo[0] is not self.trace \
+                or memo[1] != len(self.batches):
+            memo = self._kept = (self.trace, len(self.batches), {})
+        if name not in memo[2]:
+            memo[2][name] = make()
+        return memo[2][name]
+
+    def ops(self) -> trace.Ops:
+        """The trace's device ops, read into arrays once."""
+        return self.kept("ops", lambda: trace.Ops.of(self.trace["ops"]))
+
+    def busy(self) -> trace.Busy | None:
+        """The device ops of the traced window (``window``), reduced once;
+        ``None`` without a window."""
+        win = self.window()
+        return None if win is None else self.kept(
+            "busy", lambda: trace.Busy.of(self.ops(), *win))
+
     def _traced(self) -> tuple[tuple[float, float] | None, list | None]:
         """The traced window and the device ``(start, end)`` of the step of
         each dispatch in it, in ns.
@@ -157,13 +181,8 @@ class Run:
         of more dispatches holds the steps of its first ones only: then the
         traced window ends with the last step whose every op was kept, and
         the steps are those of the first dispatches. No steps where the
-        trace holds more step executions than dispatches, or none. Kept for
-        the readers while ``trace`` and ``batches`` stay the same."""
-        key = (self.trace, len(self.batches))
-        memo = getattr(self, "_traced_memo", None)
-        if memo is None or memo[0][0] is not key[0] or memo[0][1] != key[1]:
-            self._traced_memo = memo = (key, self._cut())
-        return memo[1]
+        trace holds more step executions than dispatches, or none."""
+        return self.kept("traced", self._cut)
 
     def _cut(self) -> tuple[tuple[float, float] | None, list | None]:
         win = None if self.trace is None else trace.window(self.trace)
@@ -172,7 +191,8 @@ class Run:
         found = trace.steps(self.trace, *win)
         if len(found) == len(self.batches):
             return win, found
-        last_op = max((s + d for _, s, d in self.trace["ops"]), default=0)
+        ends = self.ops().end
+        last_op = ends.max() if ends.size else 0
         kept = [step for step in found if step[1] <= last_op]
         if not kept or len(found) > len(self.batches):
             return win, None
@@ -215,6 +235,30 @@ def _no_span(_name: str):
     yield
 
 
+def read_traced(run: Run, per_layer: list[dict],
+                lap: Callable[[str], None] = lambda _name: None) -> dict:
+    """What a traced run's result takes from its trace: the per-layer
+    metrics (``metrics``), the device's busy time and the traced window's
+    length (``device``) and the ``breakdown``; the last two where the trace
+    holds the window. ``lap(name)`` is called after each reader and after
+    the rest."""
+    out: dict = {"metrics": {}}
+    for m in per_layer:
+        value = load_reader(m["name"])(run)
+        if value is not None:
+            out["metrics"][m["name"]] = {"value": value, "unit": m["unit"]}
+        lap("read " + m["name"])
+    busy = run.busy()
+    if busy is not None:
+        out["device"] = {"busy_s": busy.busy_ns * 1e-9,
+                         "window_s": (busy.hi - busy.lo) * 1e-9}
+        out["breakdown"] = {
+            "device_ops": busy.top_ops(),
+            "idle_gaps": busy.idle_by_span(run.trace["spans"])}
+    lap("busy_and_breakdown")
+    return out
+
+
 # ------------------------------------------------------------------ a run
 def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
              require_tpu: bool = True, log=print) -> dict:
@@ -230,10 +274,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     parts: dict[str, float] = {"start": process_age_s()}
     mark = time.perf_counter()
 
-    def lap(name: str) -> None:
+    def lap(name: str, into: dict[str, float] = parts) -> None:
         nonlocal mark
         now = time.perf_counter()
-        parts[name] = now - mark
+        into[name] = now - mark
         mark = now
 
     cfg, traffic = cell.cfg, cell.traffic
@@ -322,8 +366,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
             batches.append(ids)
             pos = end
     counter.armed = False
+    timed: dict[str, float] = {}    # seconds of the window and what follows
+    mark = t0
+    lap("window", timed)
     if traced:
         jax.profiler.stop_trace()
+        lap("stop_trace", timed)
     stats = dev.memory_stats() or {}
     peak = stats.get("peak_bytes_in_use")
     del step, params, rank_ofs, batch, out
@@ -341,8 +389,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     # --- correctness: every served logit against the reference ------------
     used = np.unique(pool_ids[ok])
     want = np.full(traffic.pool, np.nan, np.float32)
+    mark = time.perf_counter()
     want[used] = reference.logits(cfg, wseed, pool_idx[used],
                                   pool_dense[used])
+    lap("reference", timed)
     gap = logit_gap(served[ok], want[pool_ids[ok]]) \
         if n_done else NOT_A_NUMBER
     checks = {"logit_gap": {"value": gap, "limit": cfg["logit_gap_limit"]},
@@ -357,21 +407,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     result: dict = {"correct": bool(correct), "attempted": int(n),
                     "failed": int(n - n_done)}
     if traced:
+        mark = time.perf_counter()
         run.trace = trace.extract(logdir, SPANS)
         shutil.rmtree(logdir, ignore_errors=True)
-        win = run.window()
-        metrics = {}
-        for m in cell.per_layer:
-            value = load_reader(m["name"])(run)
-            if value is not None:
-                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-        result["metrics"] = metrics
-        if win is not None:
-            device["busy_s"] = trace.busy_ns(run.trace, *win) * 1e-9
-            device["window_s"] = (win[1] - win[0]) * 1e-9
-            result["breakdown"] = {
-                "device_ops": trace.top_ops(run.trace, *win),
-                "idle_gaps": trace.idle_by_span(run.trace, *win)}
+        lap("extract", timed)
+        got = read_traced(run, cell.per_layer,
+                          lambda name: lap(name, timed))
+        result["metrics"] = got["metrics"]
+        device.update(got.get("device", {}))
+        if "breakdown" in got:
+            result["breakdown"] = got["breakdown"]
     else:
         result["metrics"] = {
             m["name"]: {"value": e2e[m["name"]], "unit": m["unit"]}
@@ -379,7 +424,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     result["device"] = device
     dispatch_lag = np.array(dispatch_lag_us or [0.0])
     result["notes"] = {
-        "setup_parts_s": parts, "compile_cache": cache_dir,
+        "setup_parts_s": parts, "run_parts_s": timed,
+        "compile_cache": cache_dir,
         "compiles_in_window": counter.count, "dispatches": len(batches),
         "dispatch_late_us_p50_p99": [
             float(np.percentile(dispatch_lag, 50)),

@@ -14,13 +14,19 @@ the map on the run. One HLO module compiles to the same instructions under
 the same names, so the map names the ops that ran; an op of the run's steps
 that the map does not hold makes every reading null. A program without the
 scopes reads null too: no op resolves to one.
+
+The ops of the run's steps are cut to the steps once, and ``split`` takes
+every reading from that cut at once: the time of each segment of the ops'
+``op_name`` paths, so a scope nested in another (``interact/cross``) is
+read by its own name and counts in the outer one too, and the time of no
+scope. The run keeps both for the next reader.
 """
 
 from __future__ import annotations
 
-import bisect
 import collections
 import contextlib
+import dataclasses
 import re
 
 import numpy as np
@@ -77,64 +83,49 @@ def instruction(event_name: str) -> str:
     return re.split(r"[\s=]", event_name.lstrip("%"), maxsplit=1)[0]
 
 
+def segments(op_name: str) -> set[str]:
+    """The segments of the path ``op_name``: each scope it lies in, at any
+    depth."""
+    return set(op_name.split("/")) - {""}
+
+
 def in_scope(op_name: str, scope: str) -> bool:
     """``scope`` is a segment of the path ``op_name``."""
-    return scope in op_name.split("/")
+    return scope in segments(op_name)
 
 
-def _ops_by_step(tr: dict, steps: list[tuple[float, float]]
-                 ) -> list[list[tuple[str, float, float]]]:
-    """For each step, the device ops that overlap it, clipped to it."""
-    ops = tr["ops"]
-    starts = [op[1] for op in ops]
-    longest = max((op[2] for op in ops), default=0)
-    out = []
-    for lo, hi in steps:
-        i = bisect.bisect_left(starts, lo - longest)
-        j = bisect.bisect_left(starts, hi)
-        out.append([(name, max(s, lo), min(s + d, hi))
-                    for name, s, d in ops[i:j] if s + d > lo])
-    return out
+@dataclasses.dataclass(frozen=True)
+class Split:
+    """Each step's device time by scope, from one walk of the trace: for
+    every segment of the ``op_name`` of some op of the steps, the time in
+    each step in which an op with that segment ran (the union of their
+    intervals, clipped to the step), and the time in each step in which no
+    op of ``SCOPES`` ran, ``None`` when no op resolves to one."""
+
+    segment_ns: dict[str, np.ndarray]
+    unscoped_ns: np.ndarray | None
 
 
-def _resolver(scopes: dict[str, str]):
-    cache: dict[str, str] = {}
+def split(names: list[str], pieces: trace.Pieces,
+          steps: list[tuple[float, float]], scopes: dict[str, str]) -> Split:
+    """The ``Split`` of the device ops ``pieces``, cut to ``steps``
+    (``trace.Ops.clip``; their names index ``names``), each op name's
+    ``op_name`` resolved through ``scopes`` once."""
+    n = len(steps)
+    named: dict[str, list[int]] = collections.defaultdict(list)
+    for i in np.unique(pieces.name_id).tolist():
+        for segment in segments(scopes.get(instruction(names[i]), "")):
+            named[segment].append(i)
 
-    def op_name(event_name: str) -> str:
-        if event_name not in cache:
-            cache[event_name] = scopes.get(instruction(event_name), "")
-        return cache[event_name]
-    return op_name
+    def covered(ids: list[int]) -> np.ndarray:
+        mine = np.zeros(len(names), bool)
+        mine[ids] = True
+        return trace.covered_ns(pieces[mine[pieces.name_id]], n)
 
-
-def scope_ns(tr: dict, steps: list[tuple[float, float]],
-             scopes: dict[str, str], scope: str) -> list[float] | None:
-    """For each step, the time in which some device op of ``scope`` ran:
-    the union of the intervals of the ops whose ``op_name`` has ``scope`` as
-    a path segment, clipped to the step. ``None`` when no op of any step
-    resolves to ``scope``."""
-    op_name = _resolver(scopes)
-    per_step, seen = [], False
-    for ops in _ops_by_step(tr, steps):
-        mine = [(s, e) for name, s, e in ops if in_scope(op_name(name), scope)]
-        seen = seen or bool(mine)
-        per_step.append(sum(e - s for s, e in trace.union(mine)))
-    return per_step if seen else None
-
-
-def unscoped_ns(tr: dict, steps: list[tuple[float, float]],
-                scopes: dict[str, str]) -> float | None:
-    """The mean time per step, in ns, in which no op of ``SCOPES`` ran: idle
-    time inside the step and ops of no scope. ``None`` when no op of any
-    step resolves to a scope."""
-    op_name = _resolver(scopes)
-    left, seen = 0.0, False
-    for (lo, hi), ops in zip(steps, _ops_by_step(tr, steps), strict=True):
-        scoped = [(s, e) for name, s, e in ops
-                  if any(in_scope(op_name(name), sc) for sc in SCOPES)]
-        seen = seen or bool(scoped)
-        left += (hi - lo) - sum(e - s for s, e in trace.union(scoped))
-    return left / len(steps) if seen else None
+    scoped = sorted({i for scope in SCOPES for i in named.get(scope, ())})
+    step_ns = np.array([hi - lo for lo, hi in steps], np.float64)
+    return Split({segment: covered(ids) for segment, ids in named.items()},
+                 step_ns - covered(scoped) if scoped else None)
 
 
 # ------------------------------------------------- the run's compiled step
@@ -181,34 +172,53 @@ def of(run) -> dict[str, str] | None:
         found = None
         if steps:
             found = op_scopes(step_hlo(run.cfg, run.pool_indices.shape[1:]))
-            ran = {instruction(name) for ops in _ops_by_step(run.trace, steps)
-                   for name, _, _ in ops}
+            names = run.ops().names
+            ran = {instruction(names[i])
+                   for i in np.unique(_step_pieces(run).name_id).tolist()}
             if not ran <= found.keys():
                 found = None
         run.op_scopes = found
     return run.op_scopes
 
 
-def scope_ms(run, scope: str) -> float | None:
-    """Mean device time per step of the ops in the named scope ``scope``:
-    the union of their intervals, clipped to each step's execution, in ms.
-    ``None`` when no op resolves to ``scope``, so a renamed or removed scope
-    reads null, not 0."""
+def _step_pieces(run) -> trace.Pieces:
+    """The run's device ops cut to its steps, kept on the run."""
+    return run.kept("step_pieces", lambda: run.ops().clip(run.steps()))
+
+
+def split_of(run) -> Split | None:
+    """The ``split`` of the run's steps, made once and kept on the run for
+    the next reader; ``None`` where ``of(run)`` is."""
     scopes = of(run)
     if scopes is None:
         return None
-    per_step = scope_ns(run.trace, run.steps(), scopes, scope)
-    return None if per_step is None else sum(per_step) / len(per_step) * 1e-6
+    return run.kept("scope_split", lambda: split(
+        run.ops().names, _step_pieces(run), run.steps(), scopes))
+
+
+def _mean_ms(per_step: np.ndarray) -> float:
+    return float(per_step.sum()) / per_step.size * 1e-6
+
+
+def scope_ms(run, scope: str) -> float | None:
+    """Mean device time per step of the ops in the named scope ``scope``,
+    which may be any segment of their ``op_name``, nested at any depth: the
+    union of their intervals, clipped to each step's execution, in ms.
+    ``None`` when no op resolves to ``scope``, so a renamed or removed scope
+    reads null, not 0."""
+    found = split_of(run)
+    if found is None or scope not in found.segment_ns:
+        return None
+    return _mean_ms(found.segment_ns[scope])
 
 
 def unscoped_ms(run) -> float | None:
     """Mean time per step in which no op of ``SCOPES`` ran, in ms; ``None``
     when no op resolves to any scope."""
-    scopes = of(run)
-    if scopes is None:
+    found = split_of(run)
+    if found is None or found.unscoped_ns is None:
         return None
-    ns = unscoped_ns(run.trace, run.steps(), scopes)
-    return None if ns is None else ns * 1e-6
+    return _mean_ms(found.unscoped_ns)
 
 
 # ------------------------------------------------------ the SLS's roofline

@@ -1,5 +1,6 @@
 """Every entry of BENCHMARK.json resolves to the files the harness reads."""
 
+import dataclasses
 import json
 import re
 from pathlib import Path
@@ -36,26 +37,96 @@ def test_cell_resolves(cell):
     assert len(c.end_to_end) >= 2 and c.per_layer
 
 
-@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
-def test_config_file_holds_what_runs(entry):
-    from repro.serving import DeploymentConfig, arch_model_config
+# the model fields that every configuration's file states, and that the
+# harness compares with the program's own
+STATED = ("n_tables", "n_dense", "embed_dim", "n_rows", "lookups", "bot_mlp",
+          "top_mlp", "interaction")
 
-    cfg = json.loads((ROOT / entry["file"]).read_text())
-    assert cfg["name"] == entry["name"]
-    assert set(entry["reduced"]) <= set(cfg) and len(entry["source"]) <= 200
-    model = arch_model_config(DeploymentConfig.from_arch(cfg["arch"]))
-    assert (model.n_tables, model.n_dense, model.embed_dim,
-            list(model.bot_mlp), list(model.top_mlp), model.interaction) \
-        == (cfg["n_tables"], cfg["n_dense"], cfg["embed_dim"],
-            cfg["bot_mlp"], cfg["top_mlp"], cfg["interaction"])
-    assert tuple(model.n_rows) == config.per_table(cfg, "n_rows")
-    assert (model.lookups,) * model.n_tables \
-        == config.per_table(cfg, "lookups")
-    # and what the harness builds from the file is what the file states
-    assert program.deployment(cfg)[1] == model
+
+def holds_what_runs(cfg: dict) -> None:
+    """What the harness builds from the file ``cfg`` is what the file
+    states. ``program.deployment`` compares each field of the program's
+    model configuration with the file's key of that name, each per-table
+    size table by table, and raises naming the keys that differ or that the
+    program cannot take."""
+    _, model = program.deployment(cfg)
+    fields = {f.name for f in dataclasses.fields(model)}
+    assert set(STATED) <= fields & set(cfg)
+    for key in program.PER_TABLE:
+        assert program._per_table(model.n_tables, getattr(model, key)) \
+            == config.per_table(cfg, key)
     assert config.table_dtype(cfg).name == cfg["dtype"]
     assert callable(config.reference(cfg).logits)
     assert 0 < cfg["logit_gap_limit"] < 1
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_file_holds_what_runs(entry):
+    cfg = json.loads((ROOT / entry["file"]).read_text())
+    assert cfg["name"] == entry["name"]
+    assert set(entry["reduced"]) <= set(cfg) and len(entry["source"]) <= 200
+    holds_what_runs(cfg)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Model:
+    """A model configuration with a vocabulary and a pooling per table."""
+
+    name: str
+    n_tables: int
+    n_dense: int
+    embed_dim: int
+    n_rows: tuple
+    lookups: tuple
+    bot_mlp: tuple
+    top_mlp: tuple
+    interaction: str
+
+
+def _stand_in(sizes, pools: str):
+    """``from_arch`` and ``arch_model_config`` of a program that builds the
+    model its arguments state, each per-table size annotated ``sizes``;
+    with ``pools="first"`` every table pools as the first does."""
+
+    def from_arch(cls, arch: str, *, n_tables: int, n_rows: sizes,
+                  lookups: sizes, n_dense: int, embed_dim: int,
+                  bot_mlp: list, top_mlp: list, interaction: str,
+                  policies=(), batcher=None, **_deployment):
+        def each(v):
+            return tuple(v) if isinstance(v, list) else (v,) * n_tables
+        pooling = each(lookups)
+        if pools == "first":
+            pooling = pooling[:1] * n_tables
+        return _Model(arch, n_tables, n_dense, embed_dim, each(n_rows),
+                      pooling, tuple(bot_mlp), tuple(top_mlp), interaction)
+
+    return classmethod(from_arch), lambda model: model
+
+
+@pytest.mark.parametrize("sizes, pools, refused", [
+    (int | tuple[int, ...], "each", None),
+    (int, "each", "the program takes one number for every table"),
+    (int | tuple[int, ...], "first", "other sizes"),
+], ids=["takes_per_table", "takes_one_number", "pools_as_the_first"])
+def test_a_config_whose_tables_pool_differently_holds_what_runs(
+        sizes, pools, refused, monkeypatch):
+    """A file with a vocabulary and a pooling of its own for each table
+    (``TINY``) passes the check of every configuration of the benchmark
+    where the program takes and builds per-table sizes. Where it takes one
+    number, or builds another pooling, the harness refuses the file and
+    names ``lookups``."""
+    import repro.serving as serving
+    from test_bench_config import TINY
+
+    from_arch, model_config = _stand_in(sizes, pools)
+    monkeypatch.setattr(serving.DeploymentConfig, "from_arch", from_arch)
+    monkeypatch.setattr(serving, "arch_model_config", model_config)
+    if refused is None:
+        holds_what_runs(TINY)
+        return
+    with pytest.raises(config.BenchError, match=refused) as e:
+        holds_what_runs(TINY)
+    assert "lookups" in str(e.value)
 
 
 @pytest.mark.parametrize("metric", BENCH["per_layer"],

@@ -292,6 +292,38 @@ def test_a_trace_without_steps_reads_null_and_compiles_nothing(monkeypatch):
     assert all(harness.load_reader(m)(run) is None for m in NEW)
 
 
+NESTED_HLO = """HloModule jit_serve_step, is_scheduled=true
+
+ENTRY %main.5 (idx.1: s32[8]) -> f32[8] {
+  %gather.1 = f32[8]{0} gather(s32[8]{0} %idx.1), metadata={op_name="jit(serve_step)/sls/jit(_take)/gather"}
+  %dot.2 = f32[8]{0} dot(f32[8]{0} %gather.1), metadata={op_name="jit(serve_step)/interact/cross/dot_general"}
+  %add.3 = f32[8]{0} add(f32[8]{0} %dot.2), metadata={op_name="jit(serve_step)/interact/add"}
+  %copy.4 = f32[8]{0} copy(f32[8]{0} %add.3)
+  ROOT %fusion.5 = f32[8]{0} fusion(f32[8]{0} %add.3), metadata={op_name="jit(serve_step)/mlp/dot_general"}
+}
+"""
+
+
+def test_a_scope_nested_in_another_counts_in_both(monkeypatch):
+    """An op of ``interact/cross`` is read as ``cross`` and counts in
+    ``interact``, not in the time of no scope; a scope that no op has reads
+    null, not 0."""
+    monkeypatch.setattr(scopes, "step_hlo", lambda cfg, shape: NESTED_HLO)
+    _, run = _laid_out(["gather.1", "dot.2", "add.3", "copy.4", "fusion.5"])
+    per_op = 10e-6
+    assert scopes.scope_ms(run, "cross") == pytest.approx(per_op)
+    assert scopes.scope_ms(run, "interact") == pytest.approx(2 * per_op)
+    assert scopes.scope_ms(run, "sls") == pytest.approx(per_op)
+    assert scopes.scope_ms(run, "mlp") == pytest.approx(per_op)
+    # an idle ns before each op and after the last, and the orphan copy
+    assert scopes.unscoped_ms(run) == pytest.approx(6e-6 + per_op)
+    assert harness.load_reader("interact_device_ms.rate")(run) \
+        == pytest.approx(2 * per_op)
+    for absent in ("translate", "no_such_scope"):
+        assert scopes.scope_ms(run, absent) is None
+    assert harness.load_reader("translate_device_ms.rate")(run) is None
+
+
 def test_a_trace_cut_short_reads_the_dispatches_it_holds():
     """The profiler keeps a fixed number of device events and drops the
     rest, so a long window holds the steps of its first dispatches only.
@@ -324,7 +356,8 @@ def test_a_trace_cut_short_reads_the_dispatches_it_holds():
         value = harness.load_reader(metric)(cut)
         assert value is not None and value == pytest.approx(
             harness.load_reader(metric)(head)), metric
-    busy = trace.busy_ns(cut.trace, *cut.window())
+    busy = trace.Busy.of(trace.Ops.of(cut.trace["ops"]),
+                         *cut.window()).busy_ns
     assert harness.load_reader("idle_share.rate")(cut) == pytest.approx(
         100.0 * (1.0 - busy / (start + length - lo)))
     # more step executions than dispatches: no steps, as before
@@ -387,13 +420,14 @@ def test_scopes_and_unscoped_time_make_up_each_step():
         assert parts["sls"] > 0.9 * step_ns and parts[""] < 0.03 * step_ns
     run = _scoped_run(SCOPED["op_scopes"])
     steps = run.steps()
+    ops = trace.Ops.of(SCOPED["trace"]["ops"])
+    found = scopes.split(ops.names, ops.clip(steps), steps,
+                         SCOPED["op_scopes"])
     for scope in scopes.SCOPES:
-        assert scopes.scope_ns(SCOPED["trace"], steps, SCOPED["op_scopes"],
-                               scope) == pytest.approx(
+        assert found.segment_ns[scope] == pytest.approx(
             [parts[scope] for _, parts in split], abs=1e-3)
-    assert scopes.unscoped_ns(SCOPED["trace"], steps, SCOPED["op_scopes"]) \
-        == pytest.approx(np.mean([parts[""] for _, parts in split]),
-                         abs=1e-3)
+    assert found.unscoped_ns == pytest.approx(
+        [parts[""] for _, parts in split], abs=1e-3)
     step_ms = harness.load_reader("step_device_ms.rate")(run)
     total = sum(harness.load_reader(m)(run) for m in SCOPE_READERS)
     assert total + harness.load_reader("unscoped_device_ms.rate")(run) \

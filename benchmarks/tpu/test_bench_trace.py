@@ -30,6 +30,10 @@ def _segments(events, lo, hi):
             for a, b in zip(cuts[:-1], cuts[1:], strict=True)]
 
 
+def _busy(lo, hi):
+    return trace.Busy.of(trace.Ops.of(TRACE["ops"]), lo, hi)
+
+
 def test_window_is_the_harness_span():
     lo, hi = trace.window(TRACE)
     (span,) = [s for s in TRACE["spans"] if s[0] == trace.WINDOW_SPAN]
@@ -40,8 +44,8 @@ def test_busy_is_the_union_of_device_ops():
     lo, hi = trace.window(TRACE)
     want = sum(b - a for a, b, covered in _segments(TRACE["ops"], lo, hi)
                if covered)
-    assert trace.busy_ns(TRACE, lo, hi) == pytest.approx(want, abs=1e-3)
-    idle = sum(b - a for a, b in trace.idle_intervals(TRACE, lo, hi))
+    assert _busy(lo, hi).busy_ns == pytest.approx(want, abs=1e-3)
+    idle = sum(b - a for a, b in _busy(lo, hi).idle_intervals())
     assert idle + want == pytest.approx(hi - lo, abs=1e-3)
     assert 0 < want < hi - lo
 
@@ -69,20 +73,20 @@ def test_gaps_and_the_span_open_in_each():
         else:
             merged.append([a, b])
     assert [tuple(g) for g in merged] == pytest.approx(
-        trace.idle_intervals(TRACE, lo, hi))
+        _busy(lo, hi).idle_intervals())
     for a, b in merged:
         mid = 0.5 * (a + b)
         open_ = [n for n, s, d in spans if s <= mid < s + d]
         name = open_[0] if open_ else "none"
         want[name] = want.get(name, 0.0) + (b - a) * 1e-9
-    assert dict(trace.idle_by_span(TRACE, lo, hi, n=100)) \
+    assert dict(_busy(lo, hi).idle_by_span(TRACE["spans"], n=100)) \
         == pytest.approx(want)
     assert len(want) > 1
 
 
 def test_top_ops_sum_each_name_in_the_window():
     lo, hi = trace.window(TRACE)
-    top = trace.top_ops(TRACE, lo, hi, n=5)
+    top = _busy(lo, hi).top_ops(n=5)
     for name, seconds in top:
         want = sum(min(s + d, hi) - max(s, lo) for n, s, d in TRACE["ops"]
                    if n == name and s < hi and s + d > lo)

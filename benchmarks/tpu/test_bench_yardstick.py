@@ -2,7 +2,8 @@
 
 The counts are each configuration's reference module's
 (``config.reference``); those of the DLRM reference are checked against the
-program's own arithmetic and parameters.
+arithmetic and parameters of the program the harness builds from the file
+(``program.deployment``).
 """
 
 import json
@@ -10,7 +11,7 @@ from pathlib import Path
 
 import numpy as np
 import pytest
-from chipbench import config, yardstick
+from chipbench import config, program, yardstick
 
 HERE = Path(__file__).resolve().parent
 CONFIGS = sorted((HERE / "configs").glob("*.json"))
@@ -24,10 +25,8 @@ def _cfg(path: Path) -> dict:
 
 @pytest.mark.parametrize("path", DLRM, ids=lambda p: p.stem)
 def test_flops_are_the_programs_less_what_is_not_required(path):
-    from repro.serving import DeploymentConfig, arch_model_config
-
     cfg = _cfg(path)
-    model = arch_model_config(DeploymentConfig.from_arch(cfg["arch"]))
+    _, model = program.deployment(cfg)
     d, n = cfg["embed_dim"], cfg["n_tables"] + 1
     not_required = (2 * d * d                       # no embed_dim->embed_dim layer
                     + 2 * n * n * d - n * (n - 1) * d   # lower triangle, diagonal
@@ -41,11 +40,9 @@ def test_mlp_weight_bytes_match_the_programs_parameters(path):
     import jax
 
     import repro.models.dlrm as dlrm
-    from repro.serving import DeploymentConfig, arch_model_config
 
     cfg = _cfg(path)
-    model = arch_model_config(DeploymentConfig.from_arch(
-        cfg["arch"], n_rows=8))
+    _, model = program.deployment(dict(cfg, n_rows=8))
     params = jax.eval_shape(lambda: dlrm.init(jax.random.PRNGKey(0), model))
     mlp = [leaf for key in ("bot", "top")
            for leaf in jax.tree.leaves(params[key])]
