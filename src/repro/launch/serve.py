@@ -32,6 +32,7 @@ from repro.embedding.bag import pack_table
 from repro.embedding.layout import PackedRanks, RemapSpec
 from repro.flashsim.timeline import SERVING_POLICIES
 from repro.launch.compile_cache import enable_compile_cache
+from repro.models.common import mlp
 from repro.serving import (BatcherConfig, Deployment, DeploymentConfig,
                            arch_model_config)
 
@@ -92,19 +93,87 @@ def place_tables(cfg: dlrm.DLRMConfig, specs: list[RemapSpec], seed: int):
     return params, PackedRanks.stack([s.rank_of for s in specs])
 
 
+def row_buckets(rows: int) -> tuple[int, ...]:
+    """The row counts the served step can run a batch of ``rows`` rows at:
+    8, 16, 32, ... below ``rows`` (8 rows are one sublane tile), then
+    ``rows``."""
+    buckets = []
+    k = 8
+    while k < rows:
+        buckets.append(k)
+        k *= 2
+    return (*buckets, rows)
+
+
+def _live_rows(batch) -> jax.Array:
+    """1 + the index of the last row whose ids or dense features differ
+    from row 0's; the rows past it are copies of row 0. Dense features are
+    compared bit for bit, so ``-0.0`` and NaN tell a row apart."""
+    rows = batch["dense"].shape[0]
+    dense = jax.lax.bitcast_convert_type(batch["dense"], jnp.int32)
+    differs = (jnp.any(dense != dense[:1], axis=1)
+               | jnp.any((batch["indices"] != batch["indices"][:1])
+                         .reshape(rows, -1), axis=1))
+    return jnp.max(jnp.where(differs, jnp.arange(1, rows + 1), 1))
+
+
+def _on_rows(which: jax.Array, buckets: tuple[int, ...], fn) -> jax.Array:
+    """``fn(k)``, the result for the first ``k = buckets[which]`` rows, under
+    the scope ``rows_<k>``, with ``k`` chosen on the device (one
+    ``lax.switch`` branch per bucket). It is filled to ``buckets[-1]`` rows
+    with copies of its row 0: what ``fn`` gives the rows past ``k``, which
+    are copies of row 0."""
+    rows = buckets[-1]
+
+    def branch(k: int):
+        def run():
+            with jax.named_scope(f"rows_{k}"):
+                out = fn(k)
+                return jnp.concatenate([out, jnp.broadcast_to(
+                    out[:1], (rows - k, *out.shape[1:]))])
+        return run
+
+    if len(buckets) == 1:
+        return branch(rows)()
+    return jax.lax.switch(which, [branch(k) for k in buckets])
+
+
 @functools.partial(jax.jit, static_argnames="cfg")
 def serve_step(params, ranks: PackedRanks, batch, cfg: dlrm.DLRMConfig):
     """The served step: every table's logical ids translated to ranks in one
     gather (scope ``translate``), then the DLRM forward over the
-    frequency-remapped tables. The hash tables are an argument, so no
-    table-sized constant is baked into the program."""
+    frequency-remapped tables, as ``dlrm.forward`` runs it on one device.
+    The hash tables are an argument, so no table-sized constant is baked
+    into the program.
+
+    The translation and the SLS, whose work grows with the rows, run on
+    the smallest of ``row_buckets`` that holds the batch's live rows
+    (``_live_rows``); the rows past it are copies of row 0, as ``_pad``
+    makes them, and take row 0's results. The bucket is chosen on the
+    device, so one compiled step serves every fill. The MLPs and the
+    interaction run on every row."""
+    buckets = row_buckets(batch["dense"].shape[0])
+    # translate and sls switch each inside its own scope, so every op of
+    # the step, a conditional and its branches included, lies in one scope
     with jax.named_scope("translate"):
-        idx = ranks.translate(batch["indices"])
-    return dlrm.forward(params, {**batch, "indices": idx}, cfg)
+        which = jnp.sum(_live_rows(batch) > jnp.asarray(buckets[:-1]))
+        idx = _on_rows(which, buckets,
+                       lambda k: ranks.translate(batch["indices"][:k]))
+    with jax.named_scope("sls"):
+        bags = _on_rows(which, buckets, lambda k: jnp.stack([
+            dlrm._bag(params, idx[:k, t, :], t, None, None)
+            for t in range(cfg.n_tables)], axis=1))
+    with jax.named_scope("mlp"):
+        x = mlp(params["bot"], batch["dense"])
+    with jax.named_scope("interact"):
+        feat = dlrm.interact(x, bags, cfg.interaction)
+    with jax.named_scope("mlp"):
+        return mlp(params["top"], feat)[:, 0]
 
 
 def _pad(x: np.ndarray, rows: int) -> np.ndarray:
-    """Pad to ``rows`` by replicating row 0."""
+    """Pad to ``rows`` by replicating row 0: the rows ``serve_step`` skips
+    (``_live_rows``)."""
     pad = rows - x.shape[0]
     return np.concatenate([x, np.repeat(x[:1], pad, axis=0)]) if pad else x
 

@@ -6,8 +6,11 @@
 the rank_of hash tables as one array of 128-lane lines; its ``translate``
 must return exactly what a take from each table's array returns. The
 served step on what ``place_tables`` stored must score as ``dlrm.forward``
-does on the logical tables.
+does on the logical tables, whatever number of its rows are live (differ
+from row 0) and so whichever row bucket it runs.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -100,3 +103,56 @@ def test_placed_step_equals_forward(dim):
     plain = dlrm.forward(logical, batch, cfg)
     np.testing.assert_allclose(got, np.asarray(with_rank_of), atol=1e-6)
     np.testing.assert_allclose(got, np.asarray(plain), atol=1e-6)
+
+
+@pytest.mark.parametrize("rows,buckets", [(64, (8, 16, 32, 64)), (9, (8, 9)),
+                                          (8, (8,)), (5, (5,)),
+                                          (65, (8, 16, 32, 64, 65))])
+def test_row_buckets_double_from_a_sublane_tile(rows, buckets):
+    assert serve.row_buckets(rows) == buckets
+
+
+@functools.cache
+def _placed_64():
+    """``_arch(64)`` placed for the served step, and the same model for
+    ``dlrm.forward``: remapped tables and their ``rank_of``."""
+    cfg = _arch(64)
+    rng = np.random.default_rng(64)
+    specs = [RemapSpec.from_counts(rng.integers(0, 40, n)) for n in cfg.n_rows]
+    params, rank_ofs = serve.place_tables(cfg, specs, seed=7)
+    logical = dlrm.init(jax.random.PRNGKey(7), cfg)
+    remapped = {**logical, "tables": [remap_table(t, s) for t, s in
+                                      zip(logical["tables"], specs,
+                                          strict=True)]}
+    return cfg, params, rank_ofs, dlrm.add_remap(remapped, rank_ofs)
+
+
+@pytest.mark.parametrize("live,case", [
+    *[(n, "random") for n in (1, 7, 8, 9, 16, 17, 33, 63, 64)],
+    (17, "a middle row equals row 0"),
+    (64, "only the last row differs"),
+    (10, "only -0.0 where row 0 has 0.0")])
+def test_step_on_the_live_rows_equals_forward(live, case):
+    """Batches of 64 rows padded by ``serve._pad`` from ``live`` rows: the
+    step counts ``live`` rows, so it runs the bucket that holds them, and
+    scores every row as ``dlrm.forward`` does on all 64."""
+    cfg, params, rank_ofs, reference = _placed_64()
+    rng = np.random.default_rng(live)
+    dense = rng.normal(size=(live, cfg.n_dense)).astype(np.float32)
+    idx = np.stack([rng.integers(0, n, (live, cfg.lookups))
+                    for n in cfg.n_rows], axis=1)
+    if case == "a middle row equals row 0":
+        dense[live // 2], idx[live // 2] = dense[0], idx[0]
+    elif case == "only the last row differs":
+        dense[1:-1], idx[1:-1] = dense[0], idx[0]
+    elif case == "only -0.0 where row 0 has 0.0":
+        dense[0, 0] = 0.0
+        dense[1:], idx[1:] = dense[0], idx[0]
+        dense[-1, 0] = -0.0
+    batch = {"dense": jnp.asarray(serve._pad(dense, 64)),
+             "indices": jnp.asarray(serve._pad(idx, 64), jnp.int32)}
+    assert int(jax.jit(serve._live_rows)(batch)) == live
+    got = np.asarray(serve.serve_step(params, rank_ofs, batch, cfg=cfg))
+    want = jax.jit(dlrm.forward, static_argnames="cfg")(reference, batch,
+                                                        cfg=cfg)
+    np.testing.assert_allclose(got, np.asarray(want), atol=1e-6)

@@ -128,8 +128,10 @@ def test_served_forward_fits_one_chip(one_chip):
 
 
 _COPY = re.compile(r"= (\w+)\[([\d,]+)\]\S* copy(?:-start)?\(")
-_ENTRY_OP = re.compile(r"^\s+(?:ROOT )?%\S+ = .*?\s([a-z][\w-]*)\(")
+_OP = re.compile(r"^\s+(?:ROOT )?%\S+ = .*?\s([a-z][\w-]*)\(")
 _NOT_RUN = ("parameter", "get-tuple-element", "tuple", "bitcast", "constant")
+_COMPUTATION = re.compile(r"^(ENTRY )?%(\S+) .*\{$")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
 
 
 def _table_copies(hlo_text: str, table_elems: int) -> list[str]:
@@ -140,12 +142,38 @@ def _table_copies(hlo_text: str, table_elems: int) -> list[str]:
             >= table_elems]
 
 
+def _computations(hlo_text: str) -> dict[str, list[str]]:
+    """Each computation's instruction lines, by name; the entry's under
+    ``"ENTRY"``."""
+    found: dict[str, list[str]] = {}
+    lines: list[str] = []
+    for line in hlo_text.splitlines():
+        if m := _COMPUTATION.match(line):
+            lines = found.setdefault("ENTRY" if m.group(1) else m.group(2), [])
+        else:
+            lines.append(line)
+    return found
+
+
+def _branches(hlo_text: str) -> list[list[str]]:
+    """The branch computations of each ``conditional`` of the entry."""
+    return [m.group(1).replace("%", "").split(", ")
+            for line in _computations(hlo_text)["ENTRY"]
+            if (m := _BRANCHES.search(line))]
+
+
 def _device_ops(hlo_text: str) -> list[str]:
-    """The opcodes of the entry computation's instructions that run on the
-    device: one device-trace event each, every step."""
-    entry = hlo_text[hlo_text.index("\nENTRY"):]
-    return [m.group(1) for line in entry.splitlines()[1:]
-            if (m := _ENTRY_OP.match(line)) and m.group(1) not in _NOT_RUN]
+    """The opcodes of the instructions that run on the device in one step,
+    one device-trace event each: the entry computation's, and those of the
+    largest branch of each of its ``conditional``s."""
+    comps = _computations(hlo_text)
+
+    def ops(name: str) -> list[str]:
+        return [m.group(1) for line in comps[name]
+                if (m := _OP.match(line)) and m.group(1) not in _NOT_RUN]
+
+    return ops("ENTRY") + [op for names in _branches(hlo_text)
+                           for op in max(map(ops, names), key=len)]
 
 
 @pytest.mark.parametrize("placed", [True, False])
@@ -159,7 +187,9 @@ def test_served_step_gathers_from_the_tables_in_place(one_chip, placed):
     The placed step also runs at most 400 device ops, one profiler event
     each a step: a v5e profile of a 51-s window dropped its events past
     ~4.5M ops, at ~7,500 of ~10,700 steps of 599 ops (one rank_of array per
-    table, each copied into fast memory every step)."""
+    table, each copied into fast memory every step). Its translation and
+    SLS each run in one ``conditional`` with a branch per row bucket, 8,
+    16, 32 and 64 rows, of which a step runs one."""
     cfg, lowered = _served_step(one_chip, placed)
     compiled = lowered.compile()
     text = compiled.as_text()
@@ -169,6 +199,7 @@ def test_served_step_gathers_from_the_tables_in_place(one_chip, placed):
         assert copies == []
         assert temp < 128e6
         assert len(_device_ops(text)) <= 400
+        assert [len(names) for names in _branches(text)] == [4, 4]
     else:
         assert len(copies) == cfg.n_tables
         assert temp > 2 * cfg.n_rows[0] * cfg.embed_dim * 4
