@@ -5,7 +5,7 @@ the frequency-remapped tables (AF+PD), row-wise adagrad on the tables,
 AdamW on the MLPs, and the fault-tolerant TrainLoop (atomic checkpoints +
 resume). Identical to:
 
-    PYTHONPATH=src python -m repro.launch.train --model dlrm --steps 300
+    PYTHONPATH=src python -m repro.launch.train --steps 300
 
 This is the paper's offline phase + training stage (Fig. 8) end to end.
 """
@@ -15,7 +15,6 @@ import sys
 from repro.launch.train import main
 
 if __name__ == "__main__":
-    sys.argv = [sys.argv[0], "--model", "dlrm", "--steps", "300",
-                "--batch", "256", "--ckpt-dir", "/tmp/recflash_dlrm_ckpt",
-                *sys.argv[1:]]
+    sys.argv = [sys.argv[0], "--steps", "300", "--batch", "256",
+                "--ckpt-dir", "/tmp/recflash_dlrm_ckpt", *sys.argv[1:]]
     raise SystemExit(main())

@@ -1,5 +1,5 @@
-"""Regenerate the EXPERIMENTS.md §Roofline markdown tables from
-results/dryrun.json (run dryrun.py first).
+"""Print the compile-only roofline table of results/dryrun.json as
+markdown (run dryrun.py first).
 
     PYTHONPATH=src python results/make_tables.py [--mesh 16x16|2x16x16]
 """
@@ -8,11 +8,9 @@ import argparse
 import json
 import os
 
-ORDER = ["qwen3-1.7b", "qwen2-0.5b", "nemotron-4-15b", "qwen3-moe-30b-a3b",
-         "deepseek-v3-671b", "graphsage-reddit", "din", "dlrm-mlperf",
-         "dlrm-rm2", "bert4rec", "rmc1", "rmc2", "rmc3"]
-SHAPES = {"train_4k": 0, "prefill_32k": 1, "decode_32k": 2, "long_500k": 3,
-          "full_graph_sm": 0, "minibatch_lg": 1, "ogb_products": 2,
+ORDER = ["graphsage-reddit", "din", "dlrm-mlperf", "dlrm-rm2", "bert4rec",
+         "rmc1", "rmc2", "rmc3"]
+SHAPES = {"full_graph_sm": 0, "minibatch_lg": 1, "ogb_products": 2,
           "molecule": 3, "train_batch": 0, "serve_p99": 1, "serve_bulk": 2,
           "retrieval_cand": 3}
 BOUND = {"memory": "mem", "collective": "coll", "compute": "comp"}
@@ -50,10 +48,7 @@ def main():
           "| peak GB (TPU est) | fits | MODEL/HLO |")
     print("|---|---|---|---|---|---|---|---|---|---|---|---|---|---|")
     for r in rs:
-        if r["status"] == "skip":
-            print(f"| {r['arch']} | {r['shape']} | — | *skipped* "
-                  "| | | | | | | | | | |")
-        elif r["status"] == "ok":
+        if r["status"] == "ok":
             print(fmt(r))
 
 

@@ -1,17 +1,12 @@
 """Import side-effect module: populates the arch registry.
 
-Covers the LM family (qwen/nemotron/deepseek), GNN (graphsage), RecSys
-(din/dlrm/bert4rec) and the paper's own benchmark models (rmc).
+Covers GNN (graphsage), RecSys (din/dlrm/bert4rec) and the paper's own
+benchmark models (rmc).
 """
 
 import repro.configs.bert4rec_arch     # noqa: F401
-import repro.configs.deepseek_v3_671b  # noqa: F401
 import repro.configs.din_arch          # noqa: F401
 import repro.configs.dlrm_mlperf       # noqa: F401
 import repro.configs.dlrm_rm2          # noqa: F401
 import repro.configs.graphsage_reddit  # noqa: F401
-import repro.configs.nemotron_4_15b    # noqa: F401
-import repro.configs.qwen2_0_5b        # noqa: F401
-import repro.configs.qwen3_1_7b        # noqa: F401
-import repro.configs.qwen3_moe_30b_a3b  # noqa: F401
 import repro.configs.rmc               # noqa: F401

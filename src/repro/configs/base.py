@@ -4,13 +4,14 @@ A bundle owns everything the launcher needs per (arch x shape) cell:
 
 * ``init`` — full-size param init (dry-run uses ``jax.eval_shape`` on it, so
   no memory is allocated);
-* ``steps[shape]`` — the jit target (train_step / serve_step / prefill),
-  its ``input_specs()`` ShapeDtypeStructs, and sharding spec builders;
+* ``steps[shape]`` — a ``StepDef`` whose ``make_fn(bundle, mesh,
+  multi_pod)`` builds the cell's ``CellPlan``: the jit target (train_step
+  / serve_step), its ShapeDtypeStruct args and PartitionSpecs;
 * ``param_rules`` / ``opt_rules`` — path-substring -> PartitionSpec rules
   (distributed/shardings.py); opt rules default to param rules and may add
   ZeRO-style axes for optimizer state;
-* ``model_flops[shape]`` — MODEL_FLOPS (6ND for LM train; analytic for the
-  rest) for the §Roofline useful-compute ratio.
+* ``model_flops[shape]`` — analytic MODEL_FLOPS for the §Roofline
+  useful-compute ratio.
 """
 
 from __future__ import annotations
@@ -18,24 +19,43 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable
 
-from jax.sharding import PartitionSpec as P
+import jax
 
 
 @dataclasses.dataclass
 class StepDef:
-    kind: str                                  # train | serve | prefill | decode
+    kind: str                                  # train | serve
     make_fn: Callable[[Any], Callable]         # bundle -> jit-target callable
     input_specs: Callable[[bool], tuple]       # multi_pod -> args (SDS trees)
     donate: tuple = ()                         # donated argnums
     static: tuple = ()                         # static argnums
-    skip: str | None = None                    # reason if the cell is skipped
     batch_arg_axes: dict | None = None         # overrides for batch sharding
+
+
+@dataclasses.dataclass
+class CellPlan:
+    """What a step builder returns: the jit target, its ShapeDtypeStruct
+    args, and the in/out PartitionSpec trees."""
+    fn: Any
+    args: tuple
+    in_specs: tuple
+    out_specs: Any
+    donate: tuple = ()
+
+
+def bt_axes(multi_pod: bool):
+    """Mesh axes the batch dimension shards over."""
+    return ("pod", "data") if multi_pod else ("data",)
+
+
+def sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
 
 
 @dataclasses.dataclass
 class ArchBundle:
     name: str
-    family: str                                # lm | gnn | recsys
+    family: str                                # gnn | recsys
     cfg: Any
     init: Callable
     steps: dict[str, StepDef]
@@ -72,21 +92,3 @@ def list_archs() -> list[str]:
     return sorted(_REGISTRY)
 
 
-# shared PartitionSpec shorthands
-REPL = P()
-
-
-def lm_shapes():
-    """The LM-family shape set (train/prefill/decode; long_500k noted)."""
-    return {
-        "train_4k": dict(seq_len=4096, global_batch=256),
-        "prefill_32k": dict(seq_len=32768, global_batch=32),
-        "decode_32k": dict(seq_len=32768, global_batch=128),
-        # long_500k: all five assigned LM archs are pure full-attention
-        # (GQA/MLA) -> skipped per assignment rule; see DESIGN.md §4.
-    }
-
-
-LONG_500K_SKIP = ("long_500k needs sub-quadratic attention; this arch is "
-                  "pure full-attention (GQA/MLA) — skipped per assignment "
-                  "rule, documented in DESIGN.md §4")

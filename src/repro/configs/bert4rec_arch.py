@@ -8,8 +8,7 @@ import functools
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import ArchBundle, StepDef, register
-from repro.configs.lm_common import _sds
+from repro.configs.base import ArchBundle, StepDef, register, sds
 from repro.configs.recsys_common import (RECSYS_SHAPES, build_plan_generic,
                                          recsys_opt_rules, recsys_optimizer)
 from repro.models import bert4rec
@@ -27,17 +26,17 @@ def make_batch(shape_name):
         b = shp["batch"]
         t = CONFIG.seq_len
         batch = {
-            "items": _sds((b, t), jnp.int32),
-            "pad_mask": _sds((b, t), jnp.bool_),
+            "items": sds((b, t), jnp.int32),
+            "pad_mask": sds((b, t), jnp.bool_),
         }
         if shape_name == "train_batch":
             batch.update({
-                "mask_pos": _sds((b, N_MASK), jnp.int32),
-                "targets": _sds((b, N_MASK), jnp.int32),
-                "target_mask": _sds((b, N_MASK), jnp.bool_),
+                "mask_pos": sds((b, N_MASK), jnp.int32),
+                "targets": sds((b, N_MASK), jnp.int32),
+                "target_mask": sds((b, N_MASK), jnp.bool_),
             })
         if shape_name == "retrieval_cand":
-            batch["candidates"] = _sds((shp["n_candidates"],), jnp.int32)
+            batch["candidates"] = sds((shp["n_candidates"],), jnp.int32)
         return batch
     return fn
 

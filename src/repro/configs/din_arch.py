@@ -6,8 +6,7 @@ import functools
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import ArchBundle, StepDef, register
-from repro.configs.lm_common import _sds
+from repro.configs.base import ArchBundle, StepDef, register, sds
 from repro.configs.recsys_common import (RECSYS_SHAPES, build_plan_generic,
                                          recsys_opt_rules, recsys_optimizer)
 from repro.models import din
@@ -22,17 +21,17 @@ def make_batch(shape_name):
         shp = RECSYS_SHAPES[shape_name]
         b = shp["batch"]
         batch = {
-            "hist": _sds((b, CONFIG.seq_len), jnp.int32),
-            "hist_mask": _sds((b, CONFIG.seq_len), jnp.bool_),
-            "profile": _sds((b, CONFIG.n_profile), jnp.float32),
+            "hist": sds((b, CONFIG.seq_len), jnp.int32),
+            "hist_mask": sds((b, CONFIG.seq_len), jnp.bool_),
+            "profile": sds((b, CONFIG.n_profile), jnp.float32),
         }
         if shape_name == "train_batch":
-            batch["target"] = _sds((b,), jnp.int32)
-            batch["labels"] = _sds((b,), jnp.float32)
+            batch["target"] = sds((b,), jnp.int32)
+            batch["labels"] = sds((b,), jnp.float32)
         elif shape_name == "retrieval_cand":
-            batch["candidates"] = _sds((shp["n_candidates"],), jnp.int32)
+            batch["candidates"] = sds((shp["n_candidates"],), jnp.int32)
         else:
-            batch["target"] = _sds((b,), jnp.int32)
+            batch["target"] = sds((b,), jnp.int32)
         return batch
     return fn
 

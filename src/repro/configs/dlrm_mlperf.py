@@ -13,8 +13,7 @@ import functools
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import ArchBundle, StepDef, register
-from repro.configs.lm_common import _sds
+from repro.configs.base import ArchBundle, StepDef, register, sds
 from repro.configs.recsys_common import (RECSYS_SHAPES, build_plan_generic,
                                          recsys_opt_rules, recsys_optimizer)
 from repro.models import dlrm
@@ -48,15 +47,15 @@ def make_batch(cfg, shape_name, remap=True):
         shp = RECSYS_SHAPES[shape_name]
         b = shp["batch"]
         batch = {
-            "dense": _sds((b, cfg.n_dense), jnp.float32),
-            "indices": _sds((b, cfg.n_tables, cfg.lookups), jnp.int32),
+            "dense": sds((b, cfg.n_dense), jnp.float32),
+            "indices": sds((b, cfg.n_tables, cfg.lookups), jnp.int32),
         }
         if shape_name == "train_batch":
-            batch["labels"] = _sds((b,), jnp.float32)
+            batch["labels"] = sds((b,), jnp.float32)
         if shape_name == "retrieval_cand":
-            batch["candidates"] = _sds((shp["n_candidates"],), jnp.int32)
+            batch["candidates"] = sds((shp["n_candidates"],), jnp.int32)
         if remap:
-            batch["rank_of"] = [_sds((v,), jnp.int32) for v in cfg.n_rows]
+            batch["rank_of"] = [sds((v,), jnp.int32) for v in cfg.n_rows]
         return batch
     return fn
 
@@ -131,8 +130,7 @@ def make_dlrm_bundle(name, cfg, remap=True, hybrid=False, table_2d=False):
             # serving layout: 1D (model-only) tables — inference has no
             # gradient to save, and 2D costs an extra index gather +
             # wider reduction (measured: serve_bulk wire 0.21 -> 3.6 GB).
-            # Tables are resharded at deployment, exactly like the LM MoE
-            # serve rules.
+            # Tables are resharded at deployment.
             kwargs["fwd_fn"] = fwd_fn(cfg, retrieval=(s == "retrieval_cand"),
                                       hybrid=hybrid, table_2d=False)
             if table_2d:

@@ -20,8 +20,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro import optim
-from repro.configs.base import ArchBundle, StepDef, register
-from repro.configs.lm_common import CellPlan, _sds, bt_axes
+from repro.configs.base import ArchBundle, CellPlan, StepDef, bt_axes, register, sds
 from repro.distributed.shardings import make_param_specs
 from repro.models import graphsage
 
@@ -49,11 +48,11 @@ def _plan_full(bundle, mesh, multi_pod, *, cfg, shp, shard_edges):
     params = jax.eval_shape(
         functools.partial(graphsage.init, cfg=cfg), jax.random.PRNGKey(0))
     n, e = shp["n_nodes"], shp["n_edges"]
-    batch = {"feats": _sds((n, cfg.d_in), jnp.float32),
-             "edge_src": _sds((e,), jnp.int32),
-             "edge_dst": _sds((e,), jnp.int32),
-             "labels": _sds((n,), jnp.int32),
-             "train_mask": _sds((n,), jnp.float32)}
+    batch = {"feats": sds((n, cfg.d_in), jnp.float32),
+             "edge_src": sds((e,), jnp.int32),
+             "edge_dst": sds((e,), jnp.int32),
+             "labels": sds((n,), jnp.int32),
+             "train_mask": sds((n,), jnp.float32)}
     espec = P(axes) if shard_edges else P()
     b_specs = {"feats": P(), "edge_src": espec, "edge_dst": espec,
                "labels": P(), "train_mask": P()}
@@ -83,14 +82,14 @@ def _plan_minibatch(bundle, mesh, multi_pod, *, cfg):
     params = jax.eval_shape(
         functools.partial(graphsage.init, cfg=cfg), jax.random.PRNGKey(0))
     batch = {
-        "feats": _sds((dp, n0, cfg.d_in), jnp.float32),
-        "nbrs": [_sds((dp, n1, f0), jnp.int32),
-                 _sds((dp, seeds, f1), jnp.int32)],
-        "self_idx": [_sds((dp, n1), jnp.int32),
-                     _sds((dp, seeds), jnp.int32)],
-        "mask": [_sds((dp, n1, f0), jnp.bool_),
-                 _sds((dp, seeds, f1), jnp.bool_)],
-        "labels": _sds((dp, seeds), jnp.int32),
+        "feats": sds((dp, n0, cfg.d_in), jnp.float32),
+        "nbrs": [sds((dp, n1, f0), jnp.int32),
+                 sds((dp, seeds, f1), jnp.int32)],
+        "self_idx": [sds((dp, n1), jnp.int32),
+                     sds((dp, seeds), jnp.int32)],
+        "mask": [sds((dp, n1, f0), jnp.bool_),
+                 sds((dp, seeds, f1), jnp.bool_)],
+        "labels": sds((dp, seeds), jnp.int32),
     }
     b_specs = jax.tree.map(
         lambda x: P(axes, *([None] * (len(x.shape) - 1))), batch)
@@ -120,11 +119,11 @@ def _plan_molecule(bundle, mesh, multi_pod, *, cfg):
     b, n, e = shp["batch"], shp["n_nodes"], shp["n_edges"]
     params = jax.eval_shape(
         functools.partial(graphsage.init, cfg=cfg), jax.random.PRNGKey(0))
-    batch = {"x": _sds((b, n, cfg.d_in), jnp.float32),
-             "edges": _sds((b, e, 2), jnp.int32),
-             "edge_mask": _sds((b, e), jnp.bool_),
-             "node_mask": _sds((b, n), jnp.bool_),
-             "labels": _sds((b,), jnp.int32)}
+    batch = {"x": sds((b, n, cfg.d_in), jnp.float32),
+             "edges": sds((b, e, 2), jnp.int32),
+             "edge_mask": sds((b, e), jnp.bool_),
+             "node_mask": sds((b, n), jnp.bool_),
+             "labels": sds((b,), jnp.int32)}
     b_specs = jax.tree.map(
         lambda x: P(axes, *([None] * (len(x.shape) - 1))), batch)
     p_specs = make_param_specs(params, bundle.param_rules)

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro import optim
-from repro.configs.lm_common import CellPlan, bt_axes
+from repro.configs.base import CellPlan, bt_axes
 from repro.distributed.shardings import make_param_specs
 
 RECSYS_SHAPES = {

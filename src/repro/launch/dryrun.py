@@ -14,7 +14,7 @@ Per cell the script reports bytes-per-device (memory_analysis), per-device
 FLOPs/bytes (cost_analysis), the collective schedule parsed from the
 optimized HLO, and the three §Roofline terms. A cell failure (sharding
 mismatch, OOM at compile, unsupported collective) is a bug in the system —
-the run exits nonzero if any non-skipped cell fails.
+the run exits nonzero if any cell fails.
 """
 
 import argparse          # noqa: E402
@@ -41,9 +41,6 @@ def run_cell(bundle, shape: str, mesh, multi_pod: bool) -> dict:
     rec = {"arch": bundle.name, "shape": shape,
            "mesh": "2x16x16" if multi_pod else "16x16",
            "kind": step.kind}
-    if step.skip:
-        rec.update(status="skip", reason=step.skip)
-        return rec
     t0 = time.time()
     plan = step.make_fn(bundle, mesh, multi_pod)
     in_sh = _shardings(mesh, plan.in_specs)
@@ -119,8 +116,6 @@ def main() -> int:
                              f" fits={r['memory']['fits_hbm']}"
                              if r.get("memory") else ""),
                           flush=True)
-                elif rec["status"] == "skip":
-                    print(f"[skip] {tag}: {rec['reason'][:80]}", flush=True)
                 else:
                     print(f"[FAIL] {tag}: {rec['error']}", flush=True)
                     if args.verbose:
@@ -135,7 +130,6 @@ def main() -> int:
                     with open(args.out, "w") as f:
                         json.dump(results, f, indent=1)
     print(f"\n{sum(r['status'] == 'ok' for r in results)} ok / "
-          f"{sum(r['status'] == 'skip' for r in results)} skip / "
           f"{n_fail} fail")
     return 1 if n_fail else 0
 

@@ -2,8 +2,8 @@
 
 ``compiled.cost_analysis()`` visits a ``while`` body **once** — verified
 empirically: a matmul scanned 10x reports the same flops as a single matmul
-— so scanned-layer models (all five LM archs) are undercounted ~n_layers-
-fold, and it does not expose collective bytes at all. This module parses the
+— so scanned-layer models are undercounted ~n_layers-fold, and it does
+not expose collective bytes at all. This module parses the
 optimized HLO instead and attributes everything through the call graph:
 
 * **Loop trip counts** — a collective/dot/byte inside a ``while`` body

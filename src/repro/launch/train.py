@@ -1,12 +1,12 @@
 """End-to-end training driver.
 
-Trains any registered arch end-to-end on synthetic data with the
-fault-tolerant TrainLoop (checkpoint/restart, straggler hook) on whatever
-devices exist. This is the single-host path used by the examples and CI;
-the production meshes are exercised by ``dryrun.py`` (no real 512-chip
-allocation exists here).
+Trains a small DLRM (8 tables, optionally AF-remapped) end-to-end on
+synthetic data with the fault-tolerant TrainLoop (checkpoint/restart,
+straggler hook) on whatever devices exist. This is the single-host path
+used by the examples and CI; the production meshes are exercised by
+``dryrun.py`` (no real 512-chip allocation exists here).
 
-    PYTHONPATH=src python -m repro.launch.train --model dlrm \
+    PYTHONPATH=src python -m repro.launch.train \
         --steps 200 --batch 256 --ckpt-dir /tmp/ckpt
 
 For multi-host DP deployments, ``repro.distributed.compression`` provides
@@ -37,7 +37,7 @@ def small_dlrm(n_rows=50_000):
 
 
 def _dlrm_pipeline(args, remap: bool):
-    """Returns (params, opt, loss_fn, batch_fn, stats) for DLRM training."""
+    """Returns (params, opt, loss_fn, batch_fn) for DLRM training."""
     import repro.models.dlrm as dlrm
     from repro.core.freq import AccessStats
     from repro.data.tracegen import generate_sls_batch
@@ -88,35 +88,10 @@ def _dlrm_pipeline(args, remap: bool):
     return params, opt, loss_fn, batch_fn
 
 
-def _lm_pipeline(args):
-    from repro.models import lm
-    cfg = lm.LMConfig(name="lm-100m", n_layers=8, d_model=512, n_heads=8,
-                      n_kv_heads=4, d_ff=2048, vocab=32_000, qk_norm=True,
-                      tie_embeddings=True, remat=False, q_chunk=128,
-                      kv_chunk=128)
-    params = lm.init(jax.random.PRNGKey(args.seed), cfg)
-    opt = optim.adamw(args.lr, weight_decay=0.1)
-
-    def batch_fn(step):
-        rng = np.random.default_rng(step)
-        seq = args.seq_len
-        # synthetic LM data: markov-ish token stream
-        toks = rng.integers(0, cfg.vocab, (args.batch, seq + 1))
-        return {"tokens": jnp.asarray(toks[:, :-1], jnp.int32),
-                "targets": jnp.asarray(toks[:, 1:], jnp.int32)}
-
-    def loss_fn(p, batch):
-        return lm.train_loss(p, batch, cfg)
-
-    return params, opt, loss_fn, batch_fn
-
-
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--model", choices=("dlrm", "lm"), default="dlrm")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=256)
-    ap.add_argument("--seq-len", type=int, default=256)
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--lr-table", type=float, default=0.02)
     ap.add_argument("--seed", type=int, default=0)
@@ -128,14 +103,11 @@ def main() -> int:
     args = ap.parse_args()
     enable_compile_cache()
 
-    if args.model == "dlrm":
-        params, opt, loss_fn, batch_fn = _dlrm_pipeline(
-            args, remap=not args.no_remap)
-    else:
-        params, opt, loss_fn, batch_fn = _lm_pipeline(args)
+    params, opt, loss_fn, batch_fn = _dlrm_pipeline(
+        args, remap=not args.no_remap)
 
     n_params = sum(p.size for p in jax.tree.leaves(params))
-    print(f"model={args.model} params={n_params/1e6:.1f}M "
+    print(f"model=dlrm-small params={n_params/1e6:.1f}M "
           f"devices={len(jax.devices())}")
 
     @jax.jit

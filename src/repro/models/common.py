@@ -8,10 +8,6 @@ import jax
 import jax.numpy as jnp
 
 
-def uniform_init(key, shape, scale, dtype=jnp.float32):
-    return jax.random.uniform(key, shape, dtype, -scale, scale)
-
-
 def normal_init(key, shape, stddev, dtype=jnp.float32):
     return jax.random.normal(key, shape, dtype) * stddev
 
@@ -54,38 +50,5 @@ def layer_norm(x, gamma, beta, eps=1e-5):
     return (x - mu) * jax.lax.rsqrt(var + eps) * gamma + beta
 
 
-def rms_norm(x, gamma, eps=1e-6):
-    var = (x.astype(jnp.float32) ** 2).mean(-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * gamma
-
-
 def ln_init(d, dtype=jnp.float32):
     return {"gamma": jnp.ones((d,), dtype), "beta": jnp.zeros((d,), dtype)}
-
-
-def rms_init(d, dtype=jnp.float32):
-    return {"gamma": jnp.ones((d,), dtype)}
-
-
-def squared_relu(x):
-    """Primer's squared ReLU (Nemotron-4 FFN activation)."""
-    r = jax.nn.relu(x)
-    return r * r
-
-
-def rope_angles(positions, head_dim, theta=10000.0, dtype=jnp.float32):
-    """(..., T) int positions -> cos/sin of shape (..., T, head_dim/2)."""
-    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                           / head_dim))
-    ang = positions[..., None].astype(jnp.float32) * inv
-    return jnp.cos(ang).astype(dtype), jnp.sin(ang).astype(dtype)
-
-
-def apply_rope(x, cos, sin):
-    """x (..., T, H, D) with cos/sin (..., T, 1 or H, D/2)."""
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def count_params(params) -> int:
-    return sum(p.size for p in jax.tree.leaves(params))

@@ -1,10 +1,8 @@
 """Optimizers (pure JAX, optax-like API but self-contained).
 
-``sgd``, ``adamw``, ``adagrad`` (with a **row-wise** mode for embedding
+``sgd``, ``adamw`` and ``adagrad`` (with a **row-wise** mode for embedding
 tables — one accumulator per row, the industry-standard memory saving for
-10^6..10^9-row tables), and ``adafactor`` (factored second moments, the only
-footprint that lets a 671B-parameter model train on a 256-chip v5e pod —
-see EXPERIMENTS.md §Dry-run).
+10^6..10^9-row tables), composed per parameter group by ``partitioned``.
 
 API: ``opt.init(params) -> state``; ``opt.update(grads, state, params) ->
 (new_params, new_state)``.
@@ -23,11 +21,6 @@ import jax.numpy as jnp
 class Optimizer:
     init: Callable[[Any], Any]
     update: Callable[[Any, Any, Any], tuple]
-    # optional: derive opt-state PartitionSpecs structurally from the param
-    # specs (needed when state shapes differ from param shapes, e.g.
-    # adafactor's factored moments). Signature: (params_sds, param_specs)
-    # -> spec tree matching init(params).
-    state_specs: Callable[[Any, Any], Any] | None = None
 
 
 def sgd(lr: float, momentum: float = 0.0) -> Optimizer:
@@ -103,88 +96,6 @@ def adagrad(lr: float, eps: float = 1e-10,
         return new_params, new_state
 
     return Optimizer(init, update)
-
-
-def adafactor(lr: float, eps: float = 1e-30,
-              min_dim_factored: int = 128,
-              clip_threshold: float = 1.0) -> Optimizer:
-    """Factored second moments for >=2D params (rows+cols accumulators)."""
-
-    def _factored(p):
-        return p.ndim >= 2 and min(p.shape[-2:]) >= min_dim_factored
-
-    def init(params):
-        def one(p):
-            if _factored(p):
-                return {"r": jnp.zeros(p.shape[:-1], jnp.float32),
-                        "c": jnp.zeros(p.shape[:-2] + p.shape[-1:],
-                                       jnp.float32)}
-            return {"v": jnp.zeros_like(p, jnp.float32)}
-
-        return {"s": jax.tree.map(one, params),
-                "t": jnp.zeros((), jnp.int32)}
-
-    def update(grads, state, params):
-        t = state["t"] + 1
-        beta = 1.0 - (t.astype(jnp.float32) + 1.0) ** -0.8
-
-        def upd_slice(p, g, s):
-            g32 = g.astype(jnp.float32)
-            g2 = g32 * g32 + eps
-            if "r" in s:
-                r = beta * s["r"] + (1 - beta) * g2.mean(-1)
-                c = beta * s["c"] + (1 - beta) * g2.mean(-2)
-                denom = r[..., None] * c[..., None, :] \
-                    / jnp.maximum(r.mean(-1, keepdims=True), eps)[..., None]
-                upd = g32 * jax.lax.rsqrt(denom + eps)
-                new_s = {"r": r, "c": c}
-            else:
-                v = beta * s["v"] + (1 - beta) * g2
-                upd = g32 * jax.lax.rsqrt(v + eps)
-                new_s = {"v": v}
-            rms = jnp.sqrt(jnp.mean(upd * upd) + eps)
-            upd = upd / jnp.maximum(1.0, rms / clip_threshold)
-            return (p - lr * upd).astype(p.dtype), new_s
-
-        def one(p, g, s):
-            # (a lax.map-per-layer-slice variant was tried to shrink the
-            # f32 update temps; it broke XLA's donation aliasing of the
-            # stacked params and cost +13 GB net on deepseek — reverted)
-            return upd_slice(p, g, s)
-
-        flat_p, tree = jax.tree.flatten(params)
-        flat_g = tree.flatten_up_to(grads)
-        flat_s = tree.flatten_up_to(state["s"])
-        outs = [one(p, g, s) for p, g, s in zip(flat_p, flat_g, flat_s, strict=True)]
-        new_params = tree.unflatten([o[0] for o in outs])
-        new_s = tree.unflatten([o[1] for o in outs])
-        return new_params, {"s": new_s, "t": t}
-
-    def state_specs(params, param_specs):
-        """Factored stats drop a dim vs the param: derive their specs from
-        the param spec (r drops the last entry, c the second-to-last)."""
-        from jax.sharding import PartitionSpec as P
-
-        def pad(spec, ndim):
-            s = tuple(spec)
-            return s + (None,) * (ndim - len(s))
-
-        def one(p, spec):
-            if _factored(p):
-                s = pad(spec, p.ndim)
-                return {"r": P(*s[:-1]), "c": P(*(s[:-2] + s[-1:]))}
-            return {"v": spec}
-
-        return {"s": _map_specs(params, param_specs, one), "t": P()}
-
-    return Optimizer(init, update, state_specs=state_specs)
-
-
-def _map_specs(params, param_specs, fn):
-    """tree.map over (params, specs) where specs leaves are PartitionSpecs."""
-    flat_p, tree = jax.tree.flatten(params)
-    flat_s = tree.flatten_up_to(param_specs)
-    return tree.unflatten([fn(p, s) for p, s in zip(flat_p, flat_s, strict=True)])
 
 
 def partitioned(label_fn: Callable[[str], str],

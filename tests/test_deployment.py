@@ -11,7 +11,7 @@ from repro.flashsim.timeline import POLICIES, SERVING_POLICIES
 from repro.serving import (BatcherConfig, Deployment, DeploymentConfig,
                            DynamicBatcher, RequestQueue, SLOConfig,
                            ServingScheduler, TriggerConfig,
-                           build_policy_engines)
+                           arch_model_config, build_policy_engines)
 
 
 def mk_config(n_tables=2, n_rows=5_000, lookups=8, **kw):
@@ -52,6 +52,28 @@ class TestDeploymentConfig:
         assert cfg.part == "TLC"
         assert cfg.arch == "dlrm_rm2"
         assert cfg.policies == SERVING_POLICIES
+
+    @pytest.mark.parametrize("name,n_tables,n_rows,widths", [
+        # (n_dense, embed_dim, bot_mlp, top_mlp, lookups) of each source
+        ("rmc1", 8, 1_000_000, (128, 32, (64, 32), (256, 64), 80)),
+        ("rmc2", 32, 1_000_000, (256, 64, (128, 64), (128, 64), 120)),
+        ("rmc3", 10, 1_000_000,
+         (2560, 32, (1024, 256, 32), (512, 256), 20)),
+        ("dlrm_small", 8, 50_000,
+         (13, 64, (256, 128, 64), (256, 128), 20)),
+        ("dlrm_rm2", 26, 1_000_000,
+         (13, 64, (512, 256, 64), (512, 512, 256), 80)),
+        # Criteo-1TB vocabularies (up to 40M rows) cut to 1M per table
+        ("dlrm_mlperf", 26, 1_000_000,
+         (13, 128, (512, 256, 128), (1024, 1024, 512, 256), 1)),
+    ])
+    def test_from_arch_model_has_the_source_widths(self, name, n_tables,
+                                                   n_rows, widths):
+        m = arch_model_config(DeploymentConfig.from_arch(name))
+        assert (m.n_dense, m.embed_dim, m.bot_mlp, m.top_mlp,
+                m.lookups) == widths
+        assert m.n_tables == n_tables
+        assert m.n_rows == (n_rows,) * n_tables
 
     def test_from_arch_overrides_and_unknown(self):
         cfg = DeploymentConfig.from_arch("rmc1", n_rows=10_000, n_tables=4,

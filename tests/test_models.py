@@ -1,5 +1,6 @@
 """Per-arch smoke tests: reduced configs, one forward/train step on CPU,
-output shapes + no NaNs. One test class per assigned architecture family.
+output shapes + no NaNs. One test class per assigned recommender or GNN
+model.
 """
 
 import dataclasses
@@ -7,181 +8,14 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro import optim
-from repro.models import bert4rec, din, dlrm, graphsage, lm, mla, moe
+from repro.models import bert4rec, din, dlrm, graphsage
 from repro.models.dlrm import RMC1
 
 
 def _finite(x):
     return bool(jnp.isfinite(x).all())
-
-
-# ------------------------------------------------------------------ LM ----
-def small_lm(**kw):
-    base = dict(name="tiny", n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
-                d_ff=128, vocab=256, rope_theta=10_000.0, remat=False,
-                q_chunk=64, kv_chunk=64)
-    base.update(kw)
-    return lm.LMConfig(**base)
-
-
-LM_VARIANTS = {
-    # reduced stand-ins for the five assigned LM archs
-    "qwen3-1.7b": small_lm(qk_norm=True, tie_embeddings=True),
-    "qwen2-0.5b": small_lm(n_kv_heads=1, qkv_bias=True, tie_embeddings=True),
-    "nemotron-4-15b": small_lm(act="squared_relu"),
-    "qwen3-moe-30b-a3b": small_lm(
-        qk_norm=True,
-        moe=moe.MoEConfig(d_model=64, d_expert=32, n_experts=8, top_k=2,
-                          capacity_factor=2.0)),
-    "deepseek-v3-671b": small_lm(
-        n_heads=4, n_kv_heads=4, n_dense_layers=1, mtp=True,
-        mla=mla.MLAConfig(d_model=64, n_heads=4, q_lora_rank=32,
-                          kv_lora_rank=16, nope_head_dim=16,
-                          rope_head_dim=8, v_head_dim=16),
-        moe=moe.MoEConfig(d_model=64, d_expert=32, n_experts=4, top_k=2,
-                          n_shared=1, router_bias=True,
-                          capacity_factor=2.0)),
-}
-
-
-# qwen3-moe prefill/full-forward divergence, root-caused by the bisect
-# test below: GShard fixed-capacity clipping (`moe._cap_per_expert`) makes
-# expert capacity — and therefore which tokens get dropped — a function of
-# the *total token count* in the forward pass. Prefill runs t-1 tokens
-# against the full pass's t, so the two passes clip differently and their
-# logits legitimately diverge wherever a token's expert assignment was
-# dropped in one pass but not the other. Not a seedable tie-break and not
-# the KV/cache path (decode agrees to 1e-6; with clipping disabled the
-# prefill error is exactly 0), so the repro stays as a strict xfail: it
-# starts "passing" only if the capacity rule itself changes.
-MOE_CAPACITY_XFAIL = pytest.mark.xfail(
-    strict=True,
-    reason="GShard capacity clipping depends on total token count; "
-           "prefill (t-1 tokens) and full forward (t) clip differently")
-
-
-class TestLMFamily:
-    @pytest.mark.parametrize("name", sorted(LM_VARIANTS))
-    def test_train_step(self, name):
-        cfg = LM_VARIANTS[name]
-        params = lm.init(jax.random.PRNGKey(0), cfg)
-        b, t = 2, 64
-        batch = {
-            "tokens": jax.random.randint(jax.random.PRNGKey(1), (b, t), 0,
-                                         cfg.vocab, jnp.int32),
-            "targets": jax.random.randint(jax.random.PRNGKey(2), (b, t), 0,
-                                          cfg.vocab, jnp.int32),
-        }
-        loss, grads = jax.jit(jax.value_and_grad(
-            lambda p: lm.train_loss(p, batch, cfg)))(params)
-        assert _finite(loss) and loss > 0
-        assert all(_finite(g) for g in jax.tree.leaves(grads))
-        opt = optim.adamw(1e-3)
-        state = opt.init(params)
-        new_params, _ = opt.update(grads, state, params)
-        loss2 = lm.train_loss(new_params, batch, cfg)
-        assert _finite(loss2)
-
-    @pytest.mark.parametrize(
-        "name",
-        [pytest.param(n, marks=MOE_CAPACITY_XFAIL)
-         if n == "qwen3-moe-30b-a3b" else n for n in sorted(LM_VARIANTS)])
-    def test_prefill_decode_consistency(self, name):
-        """decode_step on a prefix cache must reproduce teacher-forced
-        logits from the full forward pass.
-
-        The qwen3-moe variant is a strict xfail — see MOE_CAPACITY_XFAIL:
-        its prefill-vs-full comparison diverges by construction of GShard
-        fixed-capacity routing, not by a bug in the cache path (the
-        decode-vs-full comparison below agrees to ~1e-6 even for it).
-        """
-        cfg = LM_VARIANTS[name]
-        params = lm.init(jax.random.PRNGKey(0), cfg)
-        b, t = 2, 16
-        tokens = jax.random.randint(jax.random.PRNGKey(3), (b, t), 1,
-                                    cfg.vocab, jnp.int32)
-        # full forward logits at every position
-        hidden = lm.backbone(params, tokens, cfg)
-        full_logits = lm.logits_fn(params, hidden, cfg)
-        # prefill on the first t-1 tokens, then decode token t-1
-        logits_p, cache = lm.prefill(params, tokens[:, :t - 1], cfg)
-        np.testing.assert_allclose(logits_p, full_logits[:, t - 2],
-                                   atol=2e-3)
-        # grow cache to t slots (prefill cache has t-1)
-        pad = t - (t - 1)
-        cache = jax.tree.map(
-            lambda c: jnp.pad(c, [(0, 0)] * 2 + [(0, pad)]
-                              + [(0, 0)] * (c.ndim - 3)), cache)
-        logits_d, _ = lm.decode_step(params, cache, tokens[:, t - 1],
-                                     t - 1, cfg)
-        np.testing.assert_allclose(logits_d, full_logits[:, t - 1],
-                                   atol=2e-3)
-
-    def test_moe_prefill_divergence_is_capacity_clipping(self):
-        """Bisect the qwen3-moe prefill/full divergence to its component.
-
-        Three probes isolate GShard capacity clipping (and exonerate the
-        router tie-breaking and the KV/cache path):
-
-        1. the same variant with clipping effectively disabled (a
-           capacity factor admitting every assignment) prefills
-           *exactly* equal to the full forward — so the attention/KV
-           path and the top-k router contribute zero error;
-        2. ``moe_ffn`` itself is batch-composition dependent under a
-           finite capacity: the same leading tokens produce different
-           outputs when one more token joins the batch (capacity and
-           slot competition are functions of the total token count);
-        3. with clipping disabled, that dependence vanishes bit-exactly
-           — so the divergence is the capacity rule, not expert math.
-        """
-        cfg = LM_VARIANTS["qwen3-moe-30b-a3b"]
-        b, t = 2, 16
-        tokens = jax.random.randint(jax.random.PRNGKey(3), (b, t), 1,
-                                    cfg.vocab, jnp.int32)
-        # probe 1: no-clip variant of the full prefill-vs-forward check
-        nocap = dataclasses.replace(
-            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=100.0))
-        params = lm.init(jax.random.PRNGKey(0), nocap)
-        hidden = lm.backbone(params, tokens, nocap)
-        full_logits = lm.logits_fn(params, hidden, nocap)
-        logits_p, _ = lm.prefill(params, tokens[:, :t - 1], nocap)
-        np.testing.assert_array_equal(np.asarray(logits_p),
-                                      np.asarray(full_logits[:, t - 2]))
-        # probes 2+3: moe_ffn alone, clipped vs unclipped. A tight
-        # capacity (0.5x, i.e. slots == assignments at perfect balance)
-        # guarantees slot competition at this width, so the clip-pattern
-        # dependence on total token count is visible on a single call.
-        n_tok = 64
-        mcfg = dataclasses.replace(cfg.moe, capacity_factor=0.5)
-        mp = moe.init_moe(jax.random.PRNGKey(7), mcfg)
-        x = jax.random.normal(jax.random.PRNGKey(8), (n_tok, mcfg.d_model))
-        clipped_full = moe.moe_ffn(mp, x, mcfg)[: n_tok - 1]
-        clipped_pre = moe.moe_ffn(mp, x[: n_tok - 1], mcfg)
-        assert float(jnp.abs(clipped_full - clipped_pre).max()) > 1e-6, (
-            "capacity clipping no longer depends on batch composition — "
-            "revisit MOE_CAPACITY_XFAIL, the xfail may be fixable now")
-        mnocap = dataclasses.replace(mcfg, capacity_factor=100.0)
-        open_full = moe.moe_ffn(mp, x, mnocap)[: n_tok - 1]
-        open_pre = moe.moe_ffn(mp, x[: n_tok - 1], mnocap)
-        np.testing.assert_array_equal(np.asarray(open_full),
-                                      np.asarray(open_pre))
-
-    def test_chunked_ce_matches_full(self):
-        cfg = LM_VARIANTS["qwen3-1.7b"]
-        params = lm.init(jax.random.PRNGKey(0), cfg)
-        b, t = 2, 48
-        hidden = jax.random.normal(jax.random.PRNGKey(5), (b, t, cfg.d_model))
-        targets = jax.random.randint(jax.random.PRNGKey(6), (b, t), 0,
-                                     cfg.vocab, jnp.int32)
-        full = lm.logits_fn(params, hidden, cfg).astype(jnp.float32)
-        logp = jax.nn.log_softmax(full, -1)
-        ref = -jnp.take_along_axis(logp, targets[..., None], -1).mean()
-        for chunk in (16, 48, 32):        # 32 exercises the padding path
-            out = lm.chunked_ce(params, hidden, targets, cfg, t_chunk=chunk)
-            np.testing.assert_allclose(out, ref, rtol=1e-5)
 
 
 # ------------------------------------------------------------- RecSys -----
@@ -410,29 +244,3 @@ class TestGraphSAGE:
         out = graphsage.forward_batched_graphs(params, x, edges, emask,
                                                nmask, cfg)
         assert out.shape == (8, 2) and _finite(out)
-
-
-# ---------------------------------------------------------------- MoE -----
-class TestMoE:
-    def test_high_capacity_matches_dense_routing(self):
-        cfg = moe.MoEConfig(d_model=16, d_expert=32, n_experts=4, top_k=2,
-                            capacity_factor=8.0)
-        params = moe.init_moe(jax.random.PRNGKey(0), cfg)
-        x = jax.random.normal(jax.random.PRNGKey(1), (12, 16))
-        out = moe.moe_ffn(params, x, cfg)
-        assert out.shape == x.shape and _finite(out)
-
-    def test_capacity_clipping_drops_not_corrupts(self):
-        cfg = moe.MoEConfig(d_model=16, d_expert=32, n_experts=4, top_k=1,
-                            capacity_factor=0.5)
-        params = moe.init_moe(jax.random.PRNGKey(0), cfg)
-        x = jax.random.normal(jax.random.PRNGKey(1), (16, 16))
-        out = moe.moe_ffn(params, x, cfg)
-        assert _finite(out)
-
-    def test_load_balance_loss_positive(self):
-        cfg = moe.MoEConfig(d_model=16, d_expert=32, n_experts=4, top_k=2)
-        params = moe.init_moe(jax.random.PRNGKey(0), cfg)
-        x = jax.random.normal(jax.random.PRNGKey(1), (32, 16))
-        lb = moe.load_balance_loss(params, x, cfg)
-        assert float(lb) >= 1.0 - 1e-3     # >= 1 by Cauchy-Schwarz

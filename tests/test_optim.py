@@ -35,7 +35,6 @@ class TestOptimizers:
         lambda: optim.adamw(0.05),
         lambda: optim.adagrad(0.5),
         lambda: optim.adagrad(0.5, rowwise=True),
-        lambda: optim.adafactor(0.5, min_dim_factored=4),
     ])
     def test_decreases_quadratic(self, params, make):
         _, _, losses = run_steps(make(), params)
@@ -54,24 +53,6 @@ class TestOptimizers:
         g = {"w": jnp.zeros((4,))}
         new_p, _ = opt.update(g, state, p)
         assert float(new_p["w"][0]) < 1.0            # decay with zero grad
-
-    def test_adafactor_factored_state(self):
-        opt = optim.adafactor(0.1, min_dim_factored=4)
-        p = {"big": jnp.ones((8, 6)), "small": jnp.ones((3,))}
-        state = opt.init(p)
-        assert state["s"]["big"]["r"].shape == (8,)
-        assert state["s"]["big"]["c"].shape == (6,)
-        assert state["s"]["small"]["v"].shape == (3,)
-
-    def test_adafactor_state_specs(self):
-        from jax.sharding import PartitionSpec as P
-        opt = optim.adafactor(0.1, min_dim_factored=4)
-        p = {"big": jnp.ones((8, 6)), "small": jnp.ones((3,))}
-        specs = opt.state_specs(p, {"big": P("data", "model"),
-                                    "small": P()})
-        assert specs["s"]["big"]["r"] == P("data")
-        assert specs["s"]["big"]["c"] == P("model")
-        assert specs["s"]["small"]["v"] == P()
 
     def test_partitioned_routes_by_label(self, params):
         opt = optim.partitioned(

@@ -76,7 +76,7 @@ def _arch(dim: int) -> dlrm.DLRMConfig:
                            bot_mlp=(16, dim), top_mlp=(16,))
 
 
-@pytest.mark.parametrize("dim", [32, 64, 128])
+@pytest.mark.parametrize("dim", [16, 32, 64, 96, 128])
 def test_placed_step_equals_forward(dim):
     """``place_tables`` then ``serve_step`` against ``dlrm.forward`` on the
     logical tables: remapped with ``rank_of``, and as initialised with

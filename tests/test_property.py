@@ -1,6 +1,5 @@
 """Hypothesis property tests on the system's invariants."""
 
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -10,7 +9,6 @@ from repro.core.remap import build_mapping
 from repro.embedding.layout import RemapSpec
 from repro.flashsim.device import PARTS, TIMING
 from repro.flashsim.timeline import POLICIES, SLSSimulator
-from repro.models import lm
 
 hypothesis = pytest.importorskip("hypothesis")
 
@@ -185,24 +183,3 @@ class TestAdaptiveInvariants:
         assert len(set(addrs)) == len(addrs)
         # 4) no key lost
         assert len(ht) == n + len(window)
-
-
-class TestChunkedCEProperty:
-    @given(st.integers(1, 4), st.integers(4, 64), st.integers(8, 64),
-           st.integers(2, 40), st.integers(0, 2 ** 16))
-    @settings(max_examples=20, deadline=None)
-    def test_chunked_ce_matches_full(self, b, t, vocab, chunk, seed):
-        import jax
-        cfg = lm.LMConfig(name="t", n_layers=1, d_model=8, n_heads=2,
-                          n_kv_heads=2, d_ff=16, vocab=vocab,
-                          tie_embeddings=False, remat=False)
-        params = lm.init(jax.random.PRNGKey(seed % 100), cfg)
-        hidden = jax.random.normal(jax.random.PRNGKey(seed % 97), (b, t, 8))
-        targets = jax.random.randint(jax.random.PRNGKey(seed % 89),
-                                     (b, t), 0, vocab, jnp.int32)
-        logits = lm.logits_fn(params, hidden, cfg).astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, -1)
-        ref = -jnp.take_along_axis(logp, targets[..., None], -1).mean()
-        out = lm.chunked_ce(params, hidden, targets, cfg, t_chunk=chunk)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=1e-6)
